@@ -15,11 +15,9 @@ import subprocess
 import sys
 import time
 
-from benchmarks.common import emit
+from benchmarks.common import emit, fake_device_env
 
 _CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json
 sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
@@ -56,6 +54,7 @@ def run():
     t0 = time.perf_counter()
     out = subprocess.run([sys.executable, "-c", _CHILD],
                          capture_output=True, text=True, cwd=".",
+                         env=fake_device_env(8),
                          timeout=600)
     us = (time.perf_counter() - t0) * 1e6
     line = [l for l in out.stdout.splitlines() if l.startswith("RESULT ")]

@@ -1,6 +1,7 @@
 """Shared benchmark utilities: timing, CSV emission, hardware constants."""
 from __future__ import annotations
 
+import os
 import time
 from typing import Callable, Dict, List
 
@@ -14,6 +15,17 @@ def emit(name: str, us_per_call: float, derived: str = ""):
     row = f"{name},{us_per_call:.3f},{derived}"
     ROWS.append(row)
     print(row, flush=True)
+
+
+def fake_device_env(devices: int) -> Dict[str, str]:
+    """Environment for a child process on ``devices`` fake CPU devices.
+
+    Pinned to the CPU backend: an accelerator belongs to one process at a
+    time, and the parent (or another process) may hold it."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    return env
 
 
 def time_fn(fn: Callable, *args, warmup: int = 1, iters: int = 3) -> float:

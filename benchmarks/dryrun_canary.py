@@ -20,13 +20,11 @@ import sys
 import tempfile
 import time
 
-from benchmarks.common import emit
+from benchmarks.common import emit, fake_device_env
 
 ART_DIR = pathlib.Path(__file__).resolve().parents[1] / "experiments" / "dryrun"
 
 _CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import sys, pathlib
 sys.path.insert(0, "src")
 from repro.launch.dryrun import run_cell
@@ -75,7 +73,8 @@ def run():
         with tempfile.TemporaryDirectory() as tmp:
             out = subprocess.run(
                 [sys.executable, "-c", _CHILD, arch, shape, mesh, tmp],
-                capture_output=True, text=True, cwd=".", timeout=1200)
+                capture_output=True, text=True, cwd=".",
+                env=fake_device_env(512), timeout=1200)
             if "DONE" not in out.stdout:
                 emit(f"canary.{art.stem}", 0.0,
                      f"FAILED:{out.stderr[-160:]}")

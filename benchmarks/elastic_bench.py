@@ -25,14 +25,12 @@ import subprocess
 import sys
 import time
 
-from benchmarks.common import emit
+from benchmarks.common import emit, fake_device_env
 
 OUT = pathlib.Path(__file__).resolve().parents[1] / "experiments" / \
     "BENCH_elastic.json"
 
 _CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, sys, tempfile
 sys.path.insert(0, "src")
 import numpy as np
@@ -76,6 +74,7 @@ def _executed_half():
     t0 = time.perf_counter()
     out = subprocess.run([sys.executable, "-c", _CHILD],
                          capture_output=True, text=True, cwd=".",
+                         env=fake_device_env(8),
                          timeout=1800)
     line = next((ln for ln in out.stdout.splitlines()
                  if ln.startswith("RESULT ")), None)

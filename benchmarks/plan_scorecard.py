@@ -25,14 +25,12 @@ import subprocess
 import sys
 import time
 
-from benchmarks.common import emit
+from benchmarks.common import emit, fake_device_env
 
 CONFIGS = ("qwen3-32b", "mixtral-8x22b", "gemma3-4b")
 SCENARIOS = (("single-pod", 256), ("multi-pod", 512))
 
 _PROBE_CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json, tempfile, time
 sys.path.insert(0, "src")
 from repro.configs import reduced_config, register_config
@@ -67,8 +65,6 @@ print("RESULT " + json.dumps({"chosen": str(plan.score.layout),
 
 
 _EP_PROBE_CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json, tempfile
 sys.path.insert(0, "src")
 from repro.configs import reduced_config, register_config
@@ -163,6 +159,7 @@ def run():
     t0 = time.perf_counter()
     out = subprocess.run([sys.executable, "-c", _EP_PROBE_CHILD],
                          capture_output=True, text=True, cwd=".",
+                         env=fake_device_env(8),
                          timeout=900)
     us = (time.perf_counter() - t0) * 1e6
     line = [l for l in out.stdout.splitlines() if l.startswith("RESULT ")]
@@ -182,6 +179,7 @@ def run():
     t0 = time.perf_counter()
     out = subprocess.run([sys.executable, "-c", _PROBE_CHILD],
                          capture_output=True, text=True, cwd=".",
+                         env=fake_device_env(8),
                          timeout=900)
     us = (time.perf_counter() - t0) * 1e6
     line = [l for l in out.stdout.splitlines() if l.startswith("RESULT ")]

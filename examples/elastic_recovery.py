@@ -14,8 +14,9 @@ import argparse
 import os
 import sys
 
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
+if __name__ == "__main__":
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 sys.path.insert(0, "src")
 
 import tempfile                                            # noqa: E402

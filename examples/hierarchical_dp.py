@@ -10,8 +10,9 @@ import argparse
 import os
 import sys
 
-if "--respawned" not in sys.argv and "xla_force_host_platform_device_count" \
-        not in os.environ.get("XLA_FLAGS", ""):
+if __name__ == "__main__" and "--respawned" not in sys.argv and \
+        "xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 sys.path.insert(0, "src")
@@ -23,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.collectives import hierarchical_psum, shard_map_compat
+from repro.core.collectives import hierarchical_psum
 from repro.parallel.plan import resolve_plan
 
 
@@ -79,10 +80,10 @@ def main():
         p = jax.tree.map(lambda w, gw: w - 0.3 * gw, p, g)
         return p, loss
 
-    sharded_step = jax.jit(shard_map_compat(
+    sharded_step = jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(), P(dp_axes), P(dp_axes)),
-        out_specs=(P(), P())))
+        out_specs=(P(), P()), check_vma=False))
 
     losses = []
     w_true = rng.standard_normal((D, C))      # fixed ground-truth mapping

@@ -8,8 +8,8 @@ import argparse
 import os
 import sys
 
-if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
+if __name__ == "__main__" and "xla_force_host_platform_device_count" \
+        not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 
 sys.path.insert(0, "src")

@@ -3,9 +3,8 @@
 One :class:`KernelSite` per ``pl.pallas_call`` call expression, with:
 
   * the grid (const sizes where statically known),
-  * ``dimension_semantics`` declarations from ``compiler_params``
-    (both the ``pltpu.TPUCompilerParams(...)`` and the legacy
-    ``dict(mosaic=dict(...))`` spellings),
+  * ``dimension_semantics`` declarations from
+    ``compiler_params=pltpu.CompilerParams(...)``,
   * every in/out/scratch/scalar-prefetch operand as a :class:`RefInfo`
     carrying its block shape (``None`` for non-constant extents), its
     dtype where declared (``out_shape``/``scratch_shapes``), and its
@@ -38,7 +37,7 @@ GRID_SPECS = {
     "jax.experimental.pallas.tpu.PrefetchScalarGridSpec",
     "jax.experimental.pallas.GridSpec",
 }
-TPU_COMPILER_PARAMS = "jax.experimental.pallas.tpu.TPUCompilerParams"
+TPU_COMPILER_PARAMS = "jax.experimental.pallas.tpu.CompilerParams"
 SCRATCH_CTORS = {
     "jax.experimental.pallas.tpu.VMEM",
     "jax.experimental.pallas.tpu.SMEM",
@@ -155,33 +154,16 @@ def _out_dtype(ctx: ModuleContext, struct: Optional[ast.expr]
 
 def _dim_semantics(ctx: ModuleContext, call: ast.Call, scope: ast.AST
                    ) -> Optional[Tuple[Optional[str], ...]]:
-    """``compiler_params=pltpu.TPUCompilerParams(dimension_semantics=…)``
-    or the legacy ``dict(mosaic=dict(dimension_semantics=…))`` form."""
+    """``compiler_params=pltpu.CompilerParams(dimension_semantics=…)``."""
     cp = _chase(ctx, _kwarg(call, "compiler_params"), scope)
-    if cp is None:
+    if not (isinstance(cp, ast.Call)
+            and ctx.dotted(cp.func) == TPU_COMPILER_PARAMS):
         return None
-    ds: Optional[ast.expr] = None
-    if isinstance(cp, ast.Call) and ctx.dotted(cp.func) == TPU_COMPILER_PARAMS:
-        ds = _kwarg(cp, "dimension_semantics")
-    else:
-        inner = _dict_get(cp, "mosaic")
-        ds = _dict_get(inner, "dimension_semantics") if inner is not None \
-            else _dict_get(cp, "dimension_semantics")
+    ds = _kwarg(cp, "dimension_semantics")
     if not isinstance(ds, (ast.Tuple, ast.List)):
         return None
     return tuple(e.value if isinstance(e, ast.Constant)
                  and isinstance(e.value, str) else None for e in ds.elts)
-
-
-def _dict_get(expr: Optional[ast.expr], key: str) -> Optional[ast.expr]:
-    if isinstance(expr, ast.Dict):
-        for k, v in zip(expr.keys, expr.values):
-            if isinstance(k, ast.Constant) and k.value == key:
-                return v
-    if isinstance(expr, ast.Call) and isinstance(expr.func, ast.Name) \
-            and expr.func.id == "dict":
-        return _kwarg(expr, key)
-    return None
 
 
 # -- kernel resolution -------------------------------------------------------

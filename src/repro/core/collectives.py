@@ -32,28 +32,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.optim.compression import compress_grads, decompress_grads
 
 
-def axis_size(axis_name: str) -> int:
-    """Size of a mapped mesh axis (``jax.lax.axis_size`` is jax>=0.5;
-    ``psum(1, axis)`` is the portable spelling)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, across the API move
-    (top-level ``jax.shard_map``/``check_vma`` is jax>=0.5; earlier
-    releases only have ``jax.experimental.shard_map``/``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 def hierarchical_psum(x: jax.Array, *, intra_axis: str = "data",
                       inter_axis: Optional[str] = "pod",
                       compress: str = "none") -> jax.Array:
@@ -61,7 +39,7 @@ def hierarchical_psum(x: jax.Array, *, intra_axis: str = "data",
 
     reduce-scatter over the intra (rail) axis, all-reduce the 1/N shard
     over the inter (spine) axis, all-gather back over intra."""
-    n_intra = axis_size(intra_axis)
+    n_intra = jax.lax.axis_size(intra_axis)
     if x.size % n_intra != 0:
         # fall back to flat psum for tiny/ragged tensors
         y = jax.lax.psum(x, intra_axis)
@@ -80,7 +58,7 @@ def hierarchical_psum(x: jax.Array, *, intra_axis: str = "data",
             q = jnp.round(shard / scale).astype(jnp.int8)
             # int8 summation overflows; widen to int32 on the wire-equivalent
             deq = jax.lax.psum(q.astype(jnp.int32), inter_axis)
-            scale_sum = jax.lax.psum(scale, inter_axis) / axis_size(
+            scale_sum = jax.lax.psum(scale, inter_axis) / jax.lax.axis_size(
                 inter_axis)
             shard = (deq.astype(jnp.float32) * scale_sum).astype(x.dtype)
         else:
@@ -94,7 +72,7 @@ def ring_all_reduce(x: jax.Array, axis: str) -> jax.Array:
     + all-gather ring) — the RingAllReduce pattern the paper's ECN tuning
     was validated against (§8.2).  For benchmarking/teaching; numerically
     identical to psum."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis)
@@ -140,5 +118,5 @@ def make_hierarchical_grad_reduce(mesh: Mesh, compress: str = "none"):
                               inter_axis=inter, compress=compress), g)
 
     spec = P()  # grads enter replicated-per-device (manual DP)
-    return shard_map_compat(_reduce, mesh=mesh,
-                            in_specs=(spec,), out_specs=spec)
+    return jax.shard_map(_reduce, mesh=mesh, in_specs=(spec,),
+                         out_specs=spec, check_vma=False)

@@ -18,7 +18,7 @@ import dataclasses
 import enum
 import math
 from dataclasses import dataclass, field
-from typing import Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 
 class Family(str, enum.Enum):
@@ -274,16 +274,33 @@ class RunConfig:
 
 
 # ---------------------------------------------------------------------------
-# Hardware model (TPU v5e target; used by roofline + fabric model)
+# Hardware model: per-chip peaks (roofline, fabric model, planner, MFU)
 @dataclass(frozen=True)
 class ChipSpec:
-    name: str = "tpu-v5e"
-    peak_bf16_flops: float = 197e12       # FLOP/s per chip
-    hbm_bandwidth: float = 819e9          # B/s per chip
-    ici_link_bandwidth: float = 50e9      # B/s per link (per direction)
-    ici_links_per_chip: int = 4           # 2D torus: 4 links
-    hbm_bytes: int = 16 * 2**30
-    vmem_bytes: int = 128 * 2**20
+    name: str
+    peak_bf16_flops: float                # FLOP/s per chip
+    hbm_bandwidth: float                  # B/s per chip
+    ici_link_bandwidth: float             # B/s per link (per direction)
+    ici_links_per_chip: int               # 2D torus: 4 links
+    hbm_bytes: int
+    vmem_bytes: int
 
 
-CHIP = ChipSpec()
+# Published peaks, keyed by jax's ``Device.device_kind``.  TPU v5e: Google
+# Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of ICI per chip (4 links); 128 MiB of VMEM per core.
+PEAKS: Dict[str, ChipSpec] = {
+    "TPU v5 lite": ChipSpec(name="tpu-v5e", peak_bf16_flops=197e12,
+                            hbm_bandwidth=819e9, ici_link_bandwidth=50e9,
+                            ici_links_per_chip=4, hbm_bytes=16 * 2**30,
+                            vmem_bytes=128 * 2**20),
+}
+
+# The chip the planner and roofline models target.
+CHIP = PEAKS["TPU v5 lite"]
+
+
+def chip_peaks(device_kind: Optional[str]) -> Optional[ChipSpec]:
+    """Published peaks of a device kind; None where the table has none
+    (a measurement then reports no utilization, never a guessed one)."""
+    return PEAKS.get(device_kind) if device_kind else None

@@ -2,7 +2,8 @@
 real-time telemetry enables human-in-the-loop optimization").
 
 ``RunTelemetry`` streams JSONL step records (loss, grad-norm, step time,
-tokens/s, projected MFU vs the TPU roofline) — the signals the paper's
+tokens/s, MFU against the published peak of the device kind it ran on,
+None where that kind has no entry) — the signals the paper's
 practitioners watched to decide the cancellations that dominate
 Observation 1 — plus utilization summaries compatible with the cluster
 simulator's per-job records (Observation 3's methodology).
@@ -14,16 +15,18 @@ import pathlib
 import time
 from typing import Dict, List, Optional
 
-from repro.core.config import CHIP, ModelConfig, ShapeConfig
+from repro.core.config import ModelConfig, ShapeConfig, chip_peaks
 
 
 class RunTelemetry:
     def __init__(self, path: Optional[str], cfg: ModelConfig,
-                 shape: ShapeConfig, n_chips: int = 1):
+                 shape: ShapeConfig, n_chips: int = 1,
+                 device_kind: Optional[str] = None):
         self.path = pathlib.Path(path) if path else None
         self.cfg = cfg
         self.shape = shape
         self.n_chips = n_chips
+        self.peaks = chip_peaks(device_kind)
         self._t_last = time.time()
         self._fh = self.path.open("a") if self.path else None
         self.records = []
@@ -42,8 +45,9 @@ class RunTelemetry:
             "loss": float(metrics.get("loss", float("nan"))),
             "grad_norm": float(metrics.get("grad_norm", float("nan"))),
             "tokens_per_s": tokens / max(dt, 1e-9),
-            "mfu": (self.flops_per_token * tokens / max(dt, 1e-9))
-                   / (self.n_chips * CHIP.peak_bf16_flops),
+            "mfu": None if self.peaks is None else
+                   (self.flops_per_token * tokens / max(dt, 1e-9))
+                   / (self.n_chips * self.peaks.peak_bf16_flops),
         }
         if "dropped_frac" in metrics:
             # MoE capacity-truncation drop rate (0 for dense models; a
@@ -98,16 +102,17 @@ class RunTelemetry:
         }
 
     def utilization_summary(self, low_threshold_mfu: float = 0.05) -> Dict:
-        """Observation-3-style per-job stats from the step records."""
+        """Observation-3-style per-job stats from the step records
+        (MFU fields only where the device's peaks are known)."""
         if not self.records:
             return {}
-        mfus = [r["mfu"] for r in self.records]
-        low = sum(1 for m in mfus if m < low_threshold_mfu) / len(mfus)
-        return {
-            "mean_mfu": sum(mfus) / len(mfus),
-            "low_util_fraction": low,
-            "steps": len(self.records),
-        }
+        out = {"steps": len(self.records)}
+        mfus = [r["mfu"] for r in self.records if r["mfu"] is not None]
+        if mfus:
+            out["mean_mfu"] = sum(mfus) / len(mfus)
+            out["low_util_fraction"] = sum(
+                1 for m in mfus if m < low_threshold_mfu) / len(mfus)
+        return out
 
     def close(self):
         if self._fh:

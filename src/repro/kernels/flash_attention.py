@@ -1,4 +1,4 @@
-"""Pallas TPU flash-attention forward kernel.
+"""Pallas TPU flash-attention kernel (forward) with the recompute backward.
 
 Online-softmax over KV blocks with the score tile resident in VMEM —
 the TPU-native adaptation of the memory-bounded attention the MLPerf
@@ -9,11 +9,14 @@ across kv steps.
 
 Masking is position-based ((B,S) q_pos / (B,T) k_pos with -1 = empty
 slot), so the same kernel serves training, prefill and ring-buffer
-decode.  Sliding windows ride in as a scalar-prefetch operand.
+decode.  Positions ride as (B, 1, S) rows so their blocks are (1, block)
+rows that the TPU tiling accepts at any batch size; sliding windows
+ride in as a one-element operand.
 
-Backward runs through ``repro.kernels.ref._flash``'s custom VJP (the
-recomputing flash backward); a dedicated bwd kernel is a possible further
-step and is noted in EXPERIMENTS.md §Perf.
+``flash_attention_pallas`` is differentiable: a ``custom_vjp`` whose
+forward is this kernel (which also emits the per-row log-sum-exp) and
+whose backward is the score-recomputing flash backward of
+``repro.kernels.ref`` — the same backward the jnp twin uses.
 """
 from __future__ import annotations
 
@@ -26,11 +29,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import ref as _ref
+
 NEG_INF = -1e30
 
 
 def _flash_fwd_kernel(win_ref, qpos_ref, kpos_ref, q_ref, k_ref, v_ref,
-                      o_ref, m_scr, l_scr, acc_scr, *, causal: bool,
+                      o_ref, lse_ref, m_scr, l_scr, acc_scr, *, causal: bool,
                       scale: float, kv_steps: int,
                       softcap: Optional[float]):
     ki = pl.program_id(3)
@@ -44,8 +49,8 @@ def _flash_fwd_kernel(win_ref, qpos_ref, kpos_ref, q_ref, k_ref, v_ref,
     q = q_ref[0, 0]                       # (bq, d)
     k = k_ref[0, 0]                       # (bk, d)
     v = v_ref[0, 0]                       # (bk, d)
-    qp = qpos_ref[0]                      # (bq,)
-    kp = kpos_ref[0]                      # (bk,)
+    qp = qpos_ref[0, 0]                   # (bq,)
+    kp = kpos_ref[0, 0]                   # (bk,)
     window = win_ref[0]
 
     s = jax.lax.dot_general(
@@ -76,6 +81,87 @@ def _flash_fwd_kernel(win_ref, qpos_ref, kpos_ref, q_ref, k_ref, v_ref,
         l = l_scr[...]
         o_ref[0, 0] = (acc_scr[...] /
                        jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+        # rows with no live key get +big, so the backward's exp(s - lse)
+        # is exactly zero there (the ref forward's convention)
+        lse = jnp.where(l > 0, m_scr[...] + jnp.log(jnp.maximum(l, 1e-30)),
+                        _ref.POS_BIG)
+        lse_ref[0, 0] = lse[None, :]
+
+
+def _flash_call(static, q, k, v, q_pos, k_pos, window):
+    """Kernel forward: (out (B,S,H,d), lse (B,H,S) f32)."""
+    # repro-lint: divisible — flash_attention_pallas admits only shapes
+    # for which flash_attention_fits holds
+    causal, softcap, bq, bk, interpret = static
+    B, S, H, d = q.shape
+    T = k.shape[1]
+    # kernel layout: (B, H, S, d)
+    qt = jnp.swapaxes(q, 1, 2)
+    kt = jnp.swapaxes(k, 1, 2)
+    vt = jnp.swapaxes(v, 1, 2)
+    kv_steps = T // bk
+    grid = (B, H, S // bq, kv_steps)
+
+    out, lse = pl.pallas_call(
+        functools.partial(_flash_fwd_kernel, causal=causal,
+                          scale=1.0 / math.sqrt(d), kv_steps=kv_steps,
+                          softcap=softcap),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((1,), lambda b, h, qi, ki: (0,)),          # window
+            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, 0, qi)),
+            pl.BlockSpec((1, 1, bk), lambda b, h, qi, ki: (b, 0, ki)),
+            pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b, h, qi, ki: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b, h, qi, ki: (b, h, ki, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, 1, bq), lambda b, h, qi, ki: (b, h, 0, qi)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, S, d), q.dtype),
+            jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
+        ],
+        # the output block is revisited across the kv axis (online-softmax
+        # accumulation): that dim must stay sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+    )(window, q_pos.astype(jnp.int32).reshape(B, 1, S),
+      k_pos.astype(jnp.int32).reshape(B, 1, T), qt, kt, vt)
+    return jnp.swapaxes(out, 1, 2), lse[:, :, 0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _flash(static, q, k, v, q_pos, k_pos, window):
+    return _flash_call(static, q, k, v, q_pos, k_pos, window)[0]
+
+
+def _flash_fwd(static, q, k, v, q_pos, k_pos, window):
+    out, lse = _flash_call(static, q, k, v, q_pos, k_pos, window)
+    return out, (q, k, v, q_pos, k_pos, window, out, lse)
+
+
+def _flash_bwd(static, res, dout):
+    causal, softcap, _, bk, _ = static
+    return _ref._flash_bwd((causal, softcap, bk), res, dout)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def flash_attention_fits(S: int, T: int, block_q: int = 512,
+                         block_k: int = 512) -> bool:
+    """Whether the kernel tiles (S, T): each length divides into its block
+    (a length below the block is one whole block)."""
+    return S % min(block_q, S) == 0 and T % min(block_k, T) == 0
 
 
 def flash_attention_pallas(q, k, v, q_pos, k_pos, *, causal: bool = True,
@@ -84,50 +170,13 @@ def flash_attention_pallas(q, k, v, q_pos, k_pos, *, causal: bool = True,
                            block_k: int = 512, interpret: bool = False):
     """q: (B,S,H,d); k,v: (B,T,H,d); q_pos: (B,S); k_pos: (B,T).
 
-    Returns (B,S,H,d).  Forward only — compose with the custom-VJP ref for
-    training (ops.flash_attention handles dispatch)."""
-    B, S, H, d = q.shape
-    T = k.shape[1]
-    if S % min(block_q, S) or T % min(block_k, T):
+    Returns (B,S,H,d).  Differentiable (recompute backward).  Raises
+    NotImplementedError where ``flash_attention_fits`` is false."""
+    S, T = q.shape[1], k.shape[1]
+    if not flash_attention_fits(S, T, block_q, block_k):
         raise NotImplementedError("seq not divisible by block size")
-    bq, bk = min(block_q, S), min(block_k, T)
     if window is None:
         window = 1 << 30
-    window = jnp.asarray([window], jnp.int32)
-
-    # kernel layout: (B, H, S, d)
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    kv_steps = T // bk
-    grid = (B, H, S // bq, kv_steps)
-
-    out = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, causal=causal,
-                          scale=1.0 / math.sqrt(d), kv_steps=kv_steps,
-                          softcap=softcap),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, qi, ki: (0,)),          # window
-            pl.BlockSpec((1, bq), lambda b, h, qi, ki: (b, qi)),    # q_pos
-            pl.BlockSpec((1, bk), lambda b, h, qi, ki: (b, ki)),    # k_pos
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, qi, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, qi, ki: (b, h, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
-        # the output block is revisited across the kv axis (online-softmax
-        # accumulation): that dim must stay sequential
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(window, q_pos.astype(jnp.int32), k_pos.astype(jnp.int32), qt, kt, vt)
-    return jnp.swapaxes(out, 1, 2)
+    window = jnp.asarray(window, jnp.int32).reshape(1)
+    static = (causal, softcap, min(block_q, S), min(block_k, T), interpret)
+    return _flash(static, q, k, v, q_pos, k_pos, window)

@@ -1,11 +1,11 @@
-"""Pallas TPU grouped split-KV flash-decode forward kernel.
+"""Pallas TPU grouped split-KV flash-decode forward kernels.
 
 The serving decode hot path (one query token per sequence) is memory
 bound: per decoded token the roofline-optimal kernel reads every live
 K/V cache byte exactly once.  The prefill flash kernel misses that
 optimum twice over — GQA K/V were repeated to the full head count
 before the call (``groups``× the HBM bytes) and the single-token query
-was padded to a whole q block (wasted MXU tiles).  This kernel fixes
+was padded to a whole q block (wasted MXU tiles).  These kernels fix
 both structurally:
 
   * **Native GQA layout.**  The ``groups`` q heads that share one KV
@@ -14,10 +14,25 @@ both structurally:
     q heads.  MQA (kv=1) degenerates to one big ``(H, d)`` q tile;
     MHA to ``groups=1``.
   * **Split-KV.**  The KV axis is split across the grid
-    (``grid=(B, kv_heads, kv_splits)``) flash-decode style: each
-    program emits partial ``(acc, m, l)`` for its KV block and a
-    log-sum-exp reduction epilogue combines the partials — decode
-    parallelism scales with cache length instead of query length.
+    (``grid=(B, kv_splits)``) flash-decode style: each program emits
+    partial ``(acc, m, l)`` for its KV block and a log-sum-exp
+    reduction epilogue combines the partials — decode parallelism
+    scales with cache length instead of query length.
+
+One kernel body serves four variants: a contiguous cache or a paged
+block pool (K/V blocks gathered through a scalar-prefetched block
+table), each in bf16/f32 or quantized int8/fp8 with per-(token, head)
+f32 scales dequantized in-register.
+
+TPU layout.  The cache stays in its native ``(..., tokens, K, d)``
+layout: one DMA brings a whole ``(block, K, d)`` tile — the last two
+block dims are (K, d), full array dims, which the TPU's tiling accepts
+for any kv-head count — and each kv head is a strided sublane read of
+it.  Positions ride as ``(rows, 1, tokens)`` so their blocks are
+``(1, block)`` rows, and the per-row query position and the window are
+scalar-prefetch operands in SMEM.  On a TPU the block (``block_k``, or
+the pool's ``block_size``) must be a multiple of the sublane tile:
+8 rows for f32, 16 for bf16, 32 for int8/fp8.
 
 Masking is position-based, identical to the prefill kernel: ``k_pos``
 is ``(B, T)`` int32 with -1 marking empty ring-buffer slots, ``q_pos``
@@ -26,8 +41,8 @@ is the per-row absolute decode position (true per-slot lengths from
 tanh score softcap is a static parameter.
 
 Forward only — decode never differentiates.  ``ops.flash_attention``
-dispatches S==1 calls here (``ref.flash_decode_ref`` is the pure-jnp
-CPU twin).
+and ``ops.flash_decode_paged`` dispatch here (``ref.flash_decode_ref``
+and ``ref.flash_decode_paged_ref`` are the pure-jnp twins).
 """
 from __future__ import annotations
 
@@ -43,166 +58,215 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(win_ref, qpos_ref, kpos_ref, q_ref, k_ref, v_ref,
-                   o_ref, m_ref, l_ref, *, scale: float, causal: bool,
-                   softcap: Optional[float]):
-    """One (batch row × kv head × kv split) program.
+def _decode_kernel(*refs, kv_heads: int, scale: float,
+                   causal: bool, softcap: Optional[float], paged: bool,
+                   quant: bool):
+    """One (batch row × kv block) program over every kv head.
 
-    q tile: (G, d) — all q heads of this kv head.  k/v block: (bk, d).
-    Emits the block's partial (acc, m, l); no cross-program state.
+    Scalar prefetch: [block table (paged)], window (1,), q_pos (B,).
+    Blocks: q (1, K, G, d); k/v (1, bk, K, d); k_pos (1, 1, bk);
+    [k/v scales (1, bk, K)].  Emits the block's partial (acc, m, l)
+    per kv head; no cross-program state.  A paged table entry of -1
+    kills the whole block's columns, so the pool block it was clamped
+    to — which may belong to another request — contributes nothing.
     """
-    q = q_ref[0, 0]                        # (G, d)
-    k = k_ref[0, 0]                        # (bk, d)
-    v = v_ref[0, 0]                        # (bk, d)
-    qp = qpos_ref[0, 0]                    # scalar: this row's position
-    kp = kpos_ref[0]                       # (bk,)
-    window = win_ref[0]
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale        # (G, bk)
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-
+    if paged:
+        bt_ref, win_ref, qpos_ref, *refs = refs
+    else:
+        win_ref, qpos_ref, *refs = refs
+    if quant:
+        q_ref, k_ref, v_ref, kp_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref = refs
+    else:
+        q_ref, k_ref, v_ref, kp_ref, o_ref, m_ref, l_ref = refs
+    b = pl.program_id(0)
+    qp = qpos_ref[b]                       # scalar: this row's position
+    kp = kp_ref[0]                         # (1, bk)
     valid = kp >= 0
+    if paged:
+        valid &= bt_ref[b, pl.program_id(1)] >= 0
     if causal:
         valid &= qp >= kp
-    valid &= (qp - kp) < window
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    valid &= (qp - kp) < win_ref[0]
 
-    m = s.max(axis=-1)                                     # (G,)
-    # explicit zero for masked columns: a fully-dead block yields l == 0
-    # (not bk), so the epilogue drops it instead of averaging garbage v
-    p = jnp.where(valid[None, :], jnp.exp(s - m[:, None]), 0.0)
-    l = p.sum(axis=-1)                                     # (G,)
-    acc = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                # (G, d)
+    for h in range(kv_heads):
+        q = q_ref[0, h]                    # (G, d)
+        k = k_ref[0, :, h]                 # (bk, d)
+        v = v_ref[0, :, h]
+        if quant:
+            # dequantize first: HBM streamed the narrow bytes, every
+            # contraction below runs f32
+            q = q.astype(jnp.float32)
+            k = k.astype(jnp.float32) * ks_ref[0, :, h:h + 1]
+            v = v.astype(jnp.float32) * vs_ref[0, :, h:h + 1]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # (G, bk)
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(valid, s, NEG_INF)
+        m = s.max(axis=-1, keepdims=True)                  # (G, 1)
+        # explicit zero for masked columns: a fully-dead block yields
+        # l == 0 (not bk), so the epilogue drops it instead of averaging
+        # garbage v
+        p = jnp.where(valid, jnp.exp(s - m), 0.0)
+        l = p.sum(axis=-1, keepdims=True)                  # (G, 1)
+        acc = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # (G, d)
+        o_ref[0, 0, h] = acc
+        m_ref[0, 0, h] = m
+        l_ref[0, 0, h] = l
 
-    o_ref[0, 0, 0] = acc
-    m_ref[0, 0, 0] = m
-    l_ref[0, 0, 0] = l
+
+def _decode_call(q, k, v, k_pos, q_pos, window, *, block, splits,
+                 kv_map, scale_map, pos_map, scales=None, block_tables=None,
+                 causal, softcap, interpret):
+    """Shared pallas_call for the four decode variants.
+
+    k, v: (rows, tokens, K, d) — a contiguous cache (rows = B) or a
+    pool (rows = num_blocks); k_pos: (rows, 1, tokens); scales: None
+    or (k_scale, v_scale), each (rows, tokens, K).  ``kv_map``,
+    ``scale_map`` and ``pos_map`` are the index maps of the K/V, scale
+    and position blocks over the (B, splits) grid and the
+    scalar-prefetch refs.
+    """
+    # repro-lint: divisible — the contiguous wrapper pads the cache to
+    # whole blocks, and a pool is whole blocks by construction
+    B, _, H, d = q.shape
+    K = k.shape[2]
+    G = H // K
+    paged = block_tables is not None
+    quant = scales is not None
+    if window is None:
+        window = 1 << 30
+    window = jnp.asarray(window, jnp.int32).reshape(1)
+    qp = q_pos.astype(jnp.int32).reshape(B, -1)[:, 0]
+    prefetch = ((block_tables.astype(jnp.int32),) if paged else ()) + (
+        window, qp)
+
+    q_map = lambda b, si, *_: (b, 0, 0, 0)
+    in_specs = [
+        pl.BlockSpec((1, K, G, d), q_map),
+        pl.BlockSpec((1, block, K, d), kv_map),
+        pl.BlockSpec((1, block, K, d), kv_map),
+        pl.BlockSpec((1, 1, block), pos_map),
+    ]
+    operands = [q[:, 0].reshape(B, K, G, d), k, v, k_pos.astype(jnp.int32)]
+    if quant:
+        in_specs += [pl.BlockSpec((1, block, K), scale_map)] * 2
+        operands += [s.astype(jnp.float32) for s in scales]
+    out_map = lambda b, si, *_: (b, si, 0, 0, 0)
+    o_part, m_part, l_part = pl.pallas_call(
+        functools.partial(_decode_kernel, kv_heads=K,
+                          scale=1.0 / math.sqrt(d), causal=causal,
+                          softcap=softcap, paged=paged, quant=quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(B, splits),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, K, G, d), out_map),
+                pl.BlockSpec((1, 1, K, G, 1), out_map),
+                pl.BlockSpec((1, 1, K, G, 1), out_map),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, splits, K, G, d), jnp.float32),
+            jax.ShapeDtypeStruct((B, splits, K, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, splits, K, G, 1), jnp.float32),
+        ],
+        # every grid step writes its own output block (the split
+        # combine happens outside the kernel), so all dims are parallel
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(*prefetch, *operands)
+
+    out = combine_partials(o_part, m_part, l_part)         # (B, K, G, d)
+    return out.reshape(B, 1, H, d).astype(q.dtype)
+
+
+def _check_decode(q, K):
+    S, H = q.shape[1], q.shape[2]
+    if S != 1:
+        raise NotImplementedError("flash decode handles a single query "
+                                  f"token per row (got S={S})")
+    if H % K:
+        raise NotImplementedError(f"q heads {H} not grouped over kv {K}")
+
+
+def _contiguous(q, k, v, q_pos, k_pos, scales, *, causal, window, softcap,
+                block_k, interpret):
+    B, T, K, d = k.shape
+    _check_decode(q, K)
+    bk = min(block_k, T)
+    if T % bk:
+        # pad the tail block with masked columns (k_pos = -1 marks them
+        # empty) so odd cache lengths work; padded K/V are zeros and are
+        # never read through the position mask
+        pad = ((0, 0), (0, bk - T % bk))
+        k = jnp.pad(k, pad + ((0, 0), (0, 0)))
+        v = jnp.pad(v, pad + ((0, 0), (0, 0)))
+        k_pos = jnp.pad(k_pos, pad, constant_values=-1)
+        if scales is not None:
+            scales = tuple(jnp.pad(s, pad + ((0, 0),)) for s in scales)
+        T = k.shape[1]
+    return _decode_call(
+        q, k, v,
+        k_pos.reshape(B, 1, T), q_pos, window, block=bk, splits=T // bk,
+        kv_map=lambda b, si, *_: (b, si, 0, 0),
+        scale_map=lambda b, si, *_: (b, si, 0),
+        pos_map=lambda b, si, *_: (b, 0, si),
+        scales=scales, causal=causal, softcap=softcap, interpret=interpret)
+
+
+def _paged(q, k_pool, v_pool, q_pos, kp_pool, block_tables, scales, *,
+           causal, window, softcap, interpret):
+    NB, BS, K, d = k_pool.shape
+    _check_decode(q, K)
+    # unmapped entries still index the pool (clamped to block 0); their
+    # columns are masked dead in-kernel via the table entry
+    blk = lambda b, si, bt, *_: jnp.maximum(bt[b, si], 0)
+    return _decode_call(
+        q, k_pool, v_pool,
+        kp_pool.reshape(NB, 1, BS), q_pos, window, block=BS,
+        splits=block_tables.shape[1],
+        kv_map=lambda b, si, *r: (blk(b, si, *r), 0, 0, 0),
+        scale_map=lambda b, si, *r: (blk(b, si, *r), 0, 0),
+        pos_map=lambda b, si, *r: (blk(b, si, *r), 0, 0),
+        scales=scales, block_tables=block_tables, causal=causal,
+        softcap=softcap, interpret=interpret)
 
 
 def flash_decode_pallas(q, k, v, q_pos, k_pos, *, causal: bool = True,
                         window=None, softcap: Optional[float] = None,
                         block_k: int = 512, interpret: bool = False):
-    """Grouped split-KV flash decode.
+    """Grouped split-KV flash decode over a contiguous cache.
 
     q: (B, 1, H, d) — ONE query token per row; k, v: (B, T, K, d) at the
     native kv-head count (H % K == 0, no repeat); q_pos: (B, 1) or (B,);
     k_pos: (B, T) int32 with -1 = empty slot.  ``window`` may be None,
     an int, or a traced scalar.  Returns (B, 1, H, d).
     """
-    B, S, H, d = q.shape
-    T, K = k.shape[1], k.shape[2]
-    if S != 1:
-        raise NotImplementedError("flash decode handles a single query "
-                                  f"token per row (got S={S})")
-    if H % K:
-        raise NotImplementedError(f"q heads {H} not grouped over kv {K}")
-    G = H // K
-    bk = min(block_k, T)
-    if T % bk:
-        # pad the tail block with masked columns (k_pos = -1 marks them
-        # empty) so odd cache lengths work; padded K/V are zeros and are
-        # never read through the position mask
-        pad = bk - T % bk
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        k_pos = jnp.pad(k_pos, ((0, 0), (0, pad)), constant_values=-1)
-        T += pad
-    splits = T // bk
-    if window is None:
-        window = 1 << 30
-    window = jnp.asarray(window, jnp.int32).reshape(1)
-    qp = jnp.broadcast_to(q_pos.astype(jnp.int32).reshape(B, -1)[:, :1],
-                          (B, 1))
-
-    # kernel layouts: q (B, K, G, d) — head h = k*G + g reads kv head
-    # h // G, matching the repeat_kv grouping; k/v (B, K, T, d)
-    qg = q[:, 0].reshape(B, K, G, d)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    grid = (B, K, splits)
-
-    o_part, m_part, l_part = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=1.0 / math.sqrt(d),
-                          causal=causal, softcap=softcap),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, si: (0,)),            # window
-            pl.BlockSpec((1, 1), lambda b, h, si: (b, 0)),        # q_pos
-            pl.BlockSpec((1, bk), lambda b, h, si: (b, si)),      # k_pos
-            pl.BlockSpec((1, 1, G, d), lambda b, h, si: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, si: (b, h, si, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, si: (b, h, si, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G, d),
-                         lambda b, h, si: (b, h, si, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, si: (b, h, si, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, si: (b, h, si, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, K, splits, G, d), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, splits, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, splits, G), jnp.float32),
-        ],
-        # every grid dim (incl. the split axis) maps to a distinct output
-        # block — the combine happens outside the kernel, so all parallel
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=interpret,
-    )(window, qp, k_pos.astype(jnp.int32), qg, kt, vt)
-
-    out = combine_partials(o_part, m_part, l_part)         # (B, K, G, d)
-    return out.reshape(B, 1, H, d).astype(q.dtype)
+    return _contiguous(q, k, v, q_pos, k_pos, None, causal=causal,
+                       window=window, softcap=softcap, block_k=block_k,
+                       interpret=interpret)
 
 
-def _paged_decode_kernel(bt_ref, win_ref, qpos_ref, q_ref, k_ref, v_ref,
-                         kpos_ref, o_ref, m_ref, l_ref, *, scale: float,
-                         causal: bool, softcap: Optional[float]):
-    """One (batch row × kv head × block-table entry) program.
+def flash_decode_pallas_quant(q, kq, vq, q_pos, k_pos, k_scale, v_scale,
+                              *, causal: bool = True, window=None,
+                              softcap: Optional[float] = None,
+                              block_k: int = 512,
+                              interpret: bool = False):
+    """Grouped split-KV flash decode over a quantized contiguous cache.
 
-    Same math as ``_decode_kernel`` with one addition: the K/V block was
-    gathered FROM THE GLOBAL POOL via the scalar-prefetched block table
-    (``bt_ref``), and an unmapped table entry (-1) kills the whole
-    block's columns so its pool block — which may belong to another
-    request — contributes nothing.
+    Same contract as ``flash_decode_pallas`` except ``kq, vq (B, T, K,
+    d)`` are int8/fp8 and ``k_scale, v_scale (B, T, K)`` carry the f32
+    per-(token, head) scales, blocked on the same map as their data.
     """
-    b = pl.program_id(0)
-    si = pl.program_id(2)
-    blk = bt_ref[b, si]                    # pool block id, -1 = unmapped
-    q = q_ref[0, 0]                        # (G, d)
-    k = k_ref[0, :, 0]                     # (bs, d)
-    v = v_ref[0, :, 0]                     # (bs, d)
-    qp = qpos_ref[0, 0]                    # scalar: this row's position
-    kp = kpos_ref[0]                       # (bs,)
-    window = win_ref[0]
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale        # (G, bs)
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-
-    valid = (kp >= 0) & (blk >= 0)
-    if causal:
-        valid &= qp >= kp
-    valid &= (qp - kp) < window
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m = s.max(axis=-1)                                     # (G,)
-    p = jnp.where(valid[None, :], jnp.exp(s - m[:, None]), 0.0)
-    l = p.sum(axis=-1)                                     # (G,)
-    acc = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                # (G, d)
-
-    o_ref[0, 0, 0] = acc
-    m_ref[0, 0, 0] = m
-    l_ref[0, 0, 0] = l
+    return _contiguous(q, kq, vq, q_pos, k_pos, (k_scale, v_scale),
+                       causal=causal, window=window, softcap=softcap,
+                       block_k=block_k, interpret=interpret)
 
 
 def flash_decode_paged(q, k_pool, v_pool, q_pos, kp_pool, block_tables, *,
@@ -220,236 +284,14 @@ def flash_decode_paged(q, k_pool, v_pool, q_pos, kp_pool, block_tables, *,
 
     The table rides in as a scalar-prefetch operand so the BlockSpec
     index maps gather pool blocks directly inside the Pallas grid — the
-    kv-split axis of the PR 4 kernel becomes the block-table axis and
-    the log-sum-exp combine epilogue is unchanged.  With
+    kv-split axis of the contiguous kernel becomes the block-table axis
+    and the log-sum-exp combine epilogue is unchanged.  With
     block_size == block_k the per-split arithmetic is identical to the
     contiguous kernel, so f32 outputs match bit-for-bit.
     """
-    B, S, H, d = q.shape
-    NB, BS, K, dk = k_pool.shape
-    MAXB = block_tables.shape[1]
-    if S != 1:
-        raise NotImplementedError("paged flash decode handles a single "
-                                  f"query token per row (got S={S})")
-    if H % K:
-        raise NotImplementedError(f"q heads {H} not grouped over kv {K}")
-    G = H // K
-    if window is None:
-        window = 1 << 30
-    window = jnp.asarray(window, jnp.int32).reshape(1)
-    qp = jnp.broadcast_to(q_pos.astype(jnp.int32).reshape(B, -1)[:, :1],
-                          (B, 1))
-    qg = q[:, 0].reshape(B, K, G, d)
-    bt = block_tables.astype(jnp.int32)
-    # unmapped entries still index the pool (clamped to block 0); their
-    # columns are masked dead in-kernel via blk < 0
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, K, MAXB),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, si, bt: (0,)),          # window
-            pl.BlockSpec((1, 1), lambda b, h, si, bt: (b, 0)),      # q_pos
-            pl.BlockSpec((1, 1, G, d), lambda b, h, si, bt: (b, h, 0, 0)),
-            pl.BlockSpec((1, BS, 1, d),
-                         lambda b, h, si, bt:
-                         (jnp.maximum(bt[b, si], 0), 0, h, 0)),     # k block
-            pl.BlockSpec((1, BS, 1, d),
-                         lambda b, h, si, bt:
-                         (jnp.maximum(bt[b, si], 0), 0, h, 0)),     # v block
-            pl.BlockSpec((1, BS),
-                         lambda b, h, si, bt:
-                         (jnp.maximum(bt[b, si], 0), 0)),           # k_pos
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G, d),
-                         lambda b, h, si, bt: (b, h, si, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, si, bt: (b, h, si, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, si, bt: (b, h, si, 0)),
-        ],
-    )
-    o_part, m_part, l_part = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=1.0 / math.sqrt(d),
-                          causal=causal, softcap=softcap),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, K, MAXB, G, d), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, MAXB, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, MAXB, G), jnp.float32),
-        ],
-        # the page-table gather aliases INPUT blocks only; every output
-        # block is written by exactly one grid step
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=interpret,
-    )(bt, window, qp, qg, k_pool, v_pool, kp_pool.astype(jnp.int32))
-
-    out = combine_partials(o_part, m_part, l_part)         # (B, K, G, d)
-    return out.reshape(B, 1, H, d).astype(q.dtype)
-
-
-def _decode_kernel_quant(win_ref, qpos_ref, kpos_ref, q_ref, kq_ref,
-                         vq_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, *,
-                         scale: float, causal: bool,
-                         softcap: Optional[float]):
-    """Quantized-cache twin of ``_decode_kernel``.
-
-    K/V blocks arrive int8/fp8 with one f32 scale per (token, head)
-    vector; the dequant is the first thing the kernel does (the
-    sanctioned widen-and-scale idiom RL009 recognizes), so HBM streams
-    quantized bytes while every contraction below runs f32 — the
-    split-KV partials and the LSE epilogue are untouched.
-    """
-    q = q_ref[0, 0].astype(jnp.float32)                    # (G, d)
-    k = kq_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
-    v = vq_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
-    qp = qpos_ref[0, 0]                    # scalar: this row's position
-    kp = kpos_ref[0]                       # (bk,)
-    window = win_ref[0]
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale        # (G, bk)
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-
-    valid = kp >= 0
-    if causal:
-        valid &= qp >= kp
-    valid &= (qp - kp) < window
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m = s.max(axis=-1)                                     # (G,)
-    p = jnp.where(valid[None, :], jnp.exp(s - m[:, None]), 0.0)
-    l = p.sum(axis=-1)                                     # (G,)
-    acc = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                # (G, d)
-
-    o_ref[0, 0, 0] = acc
-    m_ref[0, 0, 0] = m
-    l_ref[0, 0, 0] = l
-
-
-def flash_decode_pallas_quant(q, kq, vq, q_pos, k_pos, k_scale, v_scale,
-                              *, causal: bool = True, window=None,
-                              softcap: Optional[float] = None,
-                              block_k: int = 512,
-                              interpret: bool = False):
-    """Grouped split-KV flash decode over a quantized contiguous cache.
-
-    Same contract as ``flash_decode_pallas`` except ``kq, vq (B, T, K,
-    d)`` are int8/fp8 and ``k_scale, v_scale (B, T, K)`` carry the f32
-    per-(token, head) scales.  Scales ride the grid exactly like
-    ``k_pos``: transposed to (B, K, T) and blocked (1, 1, bk) on the
-    same (b, h, si) map as their data blocks.
-    """
-    B, S, H, d = q.shape
-    T, K = kq.shape[1], kq.shape[2]
-    if S != 1:
-        raise NotImplementedError("flash decode handles a single query "
-                                  f"token per row (got S={S})")
-    if H % K:
-        raise NotImplementedError(f"q heads {H} not grouped over kv {K}")
-    G = H // K
-    bk = min(block_k, T)
-    if T % bk:
-        pad = bk - T % bk
-        kq = jnp.pad(kq, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vq = jnp.pad(vq, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        k_scale = jnp.pad(k_scale, ((0, 0), (0, pad), (0, 0)))
-        v_scale = jnp.pad(v_scale, ((0, 0), (0, pad), (0, 0)))
-        k_pos = jnp.pad(k_pos, ((0, 0), (0, pad)), constant_values=-1)
-        T += pad
-    splits = T // bk
-    if window is None:
-        window = 1 << 30
-    window = jnp.asarray(window, jnp.int32).reshape(1)
-    qp = jnp.broadcast_to(q_pos.astype(jnp.int32).reshape(B, -1)[:, :1],
-                          (B, 1))
-
-    qg = q[:, 0].reshape(B, K, G, d)
-    kt = jnp.swapaxes(kq, 1, 2)                            # (B, K, T, d)
-    vt = jnp.swapaxes(vq, 1, 2)
-    kst = jnp.swapaxes(k_scale, 1, 2).astype(jnp.float32)  # (B, K, T)
-    vst = jnp.swapaxes(v_scale, 1, 2).astype(jnp.float32)
-    grid = (B, K, splits)
-
-    o_part, m_part, l_part = pl.pallas_call(
-        functools.partial(_decode_kernel_quant, scale=1.0 / math.sqrt(d),
-                          causal=causal, softcap=softcap),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, si: (0,)),            # window
-            pl.BlockSpec((1, 1), lambda b, h, si: (b, 0)),        # q_pos
-            pl.BlockSpec((1, bk), lambda b, h, si: (b, si)),      # k_pos
-            pl.BlockSpec((1, 1, G, d), lambda b, h, si: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, si: (b, h, si, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, si: (b, h, si, 0)),
-            pl.BlockSpec((1, 1, bk), lambda b, h, si: (b, h, si)),  # ks
-            pl.BlockSpec((1, 1, bk), lambda b, h, si: (b, h, si)),  # vs
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G, d),
-                         lambda b, h, si: (b, h, si, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, si: (b, h, si, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, si: (b, h, si, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, K, splits, G, d), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, splits, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, splits, G), jnp.float32),
-        ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=interpret,
-    )(window, qp, k_pos.astype(jnp.int32), qg, kt, vt, kst, vst)
-
-    out = combine_partials(o_part, m_part, l_part)         # (B, K, G, d)
-    return out.reshape(B, 1, H, d).astype(q.dtype)
-
-
-def _paged_decode_kernel_quant(bt_ref, win_ref, qpos_ref, q_ref, kq_ref,
-                               vq_ref, ks_ref, vs_ref, kpos_ref, o_ref,
-                               m_ref, l_ref, *, scale: float, causal: bool,
-                               softcap: Optional[float]):
-    """Quantized-cache twin of ``_paged_decode_kernel``.
-
-    The scale blocks are gathered from their own (NB, BS, K) pools via
-    the SAME block-table index map as the K/V data blocks, so a pool
-    block and its scales always travel together.
-    """
-    b = pl.program_id(0)
-    si = pl.program_id(2)
-    blk = bt_ref[b, si]                    # pool block id, -1 = unmapped
-    q = q_ref[0, 0].astype(jnp.float32)                    # (G, d)
-    k = kq_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-    v = vq_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-    qp = qpos_ref[0, 0]                    # scalar: this row's position
-    kp = kpos_ref[0]                       # (bs,)
-    window = win_ref[0]
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale        # (G, bs)
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-
-    valid = (kp >= 0) & (blk >= 0)
-    if causal:
-        valid &= qp >= kp
-    valid &= (qp - kp) < window
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m = s.max(axis=-1)                                     # (G,)
-    p = jnp.where(valid[None, :], jnp.exp(s - m[:, None]), 0.0)
-    l = p.sum(axis=-1)                                     # (G,)
-    acc = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                # (G, d)
-
-    o_ref[0, 0, 0] = acc
-    m_ref[0, 0, 0] = m
-    l_ref[0, 0, 0] = l
+    return _paged(q, k_pool, v_pool, q_pos, kp_pool, block_tables, None,
+                  causal=causal, window=window, softcap=softcap,
+                  interpret=interpret)
 
 
 def flash_decode_paged_quant(q, kq_pool, vq_pool, q_pos, kp_pool,
@@ -464,83 +306,21 @@ def flash_decode_paged_quant(q, kq_pool, vq_pool, q_pos, kp_pool,
     are the f32 scale pools, block-mapped alongside the data through the
     same scalar-prefetched table.
     """
-    B, S, H, d = q.shape
-    NB, BS, K, dk = kq_pool.shape
-    MAXB = block_tables.shape[1]
-    if S != 1:
-        raise NotImplementedError("paged flash decode handles a single "
-                                  f"query token per row (got S={S})")
-    if H % K:
-        raise NotImplementedError(f"q heads {H} not grouped over kv {K}")
-    G = H // K
-    if window is None:
-        window = 1 << 30
-    window = jnp.asarray(window, jnp.int32).reshape(1)
-    qp = jnp.broadcast_to(q_pos.astype(jnp.int32).reshape(B, -1)[:, :1],
-                          (B, 1))
-    qg = q[:, 0].reshape(B, K, G, d)
-    bt = block_tables.astype(jnp.int32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, K, MAXB),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, si, bt: (0,)),          # window
-            pl.BlockSpec((1, 1), lambda b, h, si, bt: (b, 0)),      # q_pos
-            pl.BlockSpec((1, 1, G, d), lambda b, h, si, bt: (b, h, 0, 0)),
-            pl.BlockSpec((1, BS, 1, d),
-                         lambda b, h, si, bt:
-                         (jnp.maximum(bt[b, si], 0), 0, h, 0)),     # k block
-            pl.BlockSpec((1, BS, 1, d),
-                         lambda b, h, si, bt:
-                         (jnp.maximum(bt[b, si], 0), 0, h, 0)),     # v block
-            pl.BlockSpec((1, BS, 1),
-                         lambda b, h, si, bt:
-                         (jnp.maximum(bt[b, si], 0), 0, h)),        # k scale
-            pl.BlockSpec((1, BS, 1),
-                         lambda b, h, si, bt:
-                         (jnp.maximum(bt[b, si], 0), 0, h)),        # v scale
-            pl.BlockSpec((1, BS),
-                         lambda b, h, si, bt:
-                         (jnp.maximum(bt[b, si], 0), 0)),           # k_pos
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G, d),
-                         lambda b, h, si, bt: (b, h, si, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, si, bt: (b, h, si, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, si, bt: (b, h, si, 0)),
-        ],
-    )
-    o_part, m_part, l_part = pl.pallas_call(
-        functools.partial(_paged_decode_kernel_quant,
-                          scale=1.0 / math.sqrt(d),
-                          causal=causal, softcap=softcap),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, K, MAXB, G, d), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, MAXB, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, MAXB, G), jnp.float32),
-        ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=interpret,
-    )(bt, window, qp, qg, kq_pool, vq_pool,
-      ks_pool.astype(jnp.float32), vs_pool.astype(jnp.float32),
-      kp_pool.astype(jnp.int32))
-
-    out = combine_partials(o_part, m_part, l_part)         # (B, K, G, d)
-    return out.reshape(B, 1, H, d).astype(q.dtype)
+    return _paged(q, kq_pool, vq_pool, q_pos, kp_pool, block_tables,
+                  (ks_pool, vs_pool), causal=causal, window=window,
+                  softcap=softcap, interpret=interpret)
 
 
 def combine_partials(o_part, m_part, l_part):
     """Log-sum-exp reduction over the split axis.
 
-    o_part: (B, K, splits, G, d); m_part, l_part: (B, K, splits, G).
+    o_part: (B, splits, K, G, d); m_part, l_part: (B, splits, K, G, 1).
     Fully-dead splits carry (m=NEG_INF, l=0) and contribute nothing; a
     row with NO live key anywhere returns zeros (matches the oracle's
     zeroing of fully-masked rows).
     """
-    m_star = m_part.max(axis=2)                            # (B, K, G)
-    alpha = jnp.exp(m_part - m_star[:, :, None])           # (B, K, s, G)
-    l_star = (l_part * alpha).sum(axis=2)                  # (B, K, G)
-    acc = (o_part * alpha[..., None]).sum(axis=2)          # (B, K, G, d)
-    return acc / jnp.maximum(l_star, 1e-30)[..., None]
+    m_star = m_part.max(axis=1, keepdims=True)             # (B, 1, K, G, 1)
+    alpha = jnp.exp(m_part - m_star)                       # (B, s, K, G, 1)
+    l_star = (l_part * alpha).sum(axis=1)                  # (B, K, G, 1)
+    acc = (o_part * alpha).sum(axis=1)                     # (B, K, G, d)
+    return acc / jnp.maximum(l_star, 1e-30)

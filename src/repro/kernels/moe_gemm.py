@@ -60,14 +60,32 @@ def _moe_kernel(eid_ref, cnt_ref, x_ref, w1_ref, w3_ref, w2_ref, o_ref,
         o_ref[...] = acc_scr[...].astype(o_ref.dtype)
 
 
-def _moe_gemm_call(xe, counts, w1, w3, w2, *, act: str, block_rows,
-                   block_f, interpret: bool):
-    B, E, C, D = xe.shape
+def _padded(C: int) -> int:
+    """Capacity rounded up to whole 8-row sublane tiles."""
+    return -(-C // 8) * 8
+
+
+def _vmem_limit(bm: int, bf: int, D: int, x_bytes: int, w_bytes: int) -> int:
+    """Scoped-VMEM bytes one grid step needs, with a quarter headroom.
+
+    Double-buffered x and out row tiles and w1/w3/w2 weight tiles, the
+    f32 accumulator, and the step's f32 hidden tiles.  At Mixtral widths
+    (D = 6144, bf = 512) this is ~60 MiB: above the 16 MiB a TPU v5e
+    scopes by default, well inside its 128 MiB of VMEM.
+    """
+    need = (2 * (2 * bm * D * x_bytes + 3 * D * bf * w_bytes)
+            + bm * D * 4 + 3 * bm * bf * 4)
+    return need + need // 4
+
+
+def _moe_gemm_call(xe, counts, w1, w3, w2, *, act: str, bm: int, bf: int,
+                   interpret: bool):
+    B, E, C0, D = xe.shape
     F = w1.shape[-1]
-    bm = block_rows if block_rows is not None else \
-        (128 if C % 128 == 0 else C)
-    bf = block_f if block_f is not None else \
-        (512 if F % 512 == 0 else F)
+    # pad capacity to whole 8-row sublane tiles (a TPU row block must be
+    # one); the pad rows are zeros, which the FFN maps to zeros
+    C = _padded(C0)
+    xe = jnp.pad(xe, ((0, 0), (0, 0), (0, C - C0), (0, 0)))
     if C % bm or F % bf:
         raise NotImplementedError("dims not divisible by block")
     act_fn = resolve_moe_act(act)
@@ -103,11 +121,13 @@ def _moe_gemm_call(xe, counts, w1, w3, w2, *, act: str, block_rows,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * E * C, D), xe.dtype),
         # each (row-block) output tile accumulates over the F axis
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(bm, bf, D, xe.dtype.itemsize,
+                                         w1.dtype.itemsize)),
         interpret=interpret,
     )(expert_ids, block_valid, xr, w1, w3, w2)
-    return out.reshape(B, E, C, D)
+    return out.reshape(B, E, C, D)[:, :, :C0]
 
 
 # pallas_call has no autodiff rule; training differentiates the MoE FFN,
@@ -117,8 +137,8 @@ def _moe_gemm_call(xe, counts, w1, w3, w2, *, act: str, block_rows,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _moe_gemm(static, xe, counts, w1, w3, w2):
     act, bm, bf, interpret = static
-    return _moe_gemm_call(xe, counts, w1, w3, w2, act=act, block_rows=bm,
-                          block_f=bf, interpret=interpret)
+    return _moe_gemm_call(xe, counts, w1, w3, w2, act=act, bm=bm, bf=bf,
+                          interpret=interpret)
 
 
 def _moe_gemm_fwd(static, xe, counts, w1, w3, w2):
@@ -149,8 +169,8 @@ def moe_gemm_pallas(xe, counts, w1, w3, w2, *, act: str = "silu",
     valid rows per block (rank-ordered prefix); w1, w3: (E, D, F);
     w2: (E, F, D).  Returns (B, E, C, D) in ``xe.dtype``.
 
-    Raises NotImplementedError when C/F are not divisible by the row/F
-    block so ``ops.moe_gemm`` can fall back to the jnp twin.
+    Raises NotImplementedError when C/F are not divisible by an
+    explicit row/F block.
     """
     B, E, C, D = xe.shape
     E2, D2, F = w1.shape
@@ -158,10 +178,8 @@ def moe_gemm_pallas(xe, counts, w1, w3, w2, *, act: str = "silu",
         raise ValueError(f"inconsistent expert weight shapes "
                          f"{w1.shape}/{w3.shape}/{w2.shape} for xe {xe.shape}")
     bm = block_rows if block_rows is not None else \
-        (128 if C % 128 == 0 else C)
+        (128 if _padded(C) % 128 == 0 else _padded(C))
     bf = block_f if block_f is not None else \
         (512 if F % 512 == 0 else F)
-    if C % bm or F % bf:
-        raise NotImplementedError("dims not divisible by block")
     resolve_moe_act(act)      # raise ValueError early on bad names
     return _moe_gemm((act, bm, bf, interpret), xe, counts, w1, w3, w2)

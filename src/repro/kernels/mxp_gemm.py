@@ -53,14 +53,23 @@ def _mxp_kernel(a_ref, b_ref, o_ref, acc_scr, *, k_steps: int):
         o_ref[...] = acc_scr[...].astype(o_ref.dtype)
 
 
+def mxp_gemm_fits(M: int, N: int, K: int, *, block: int = 128,
+                  block_m: int = 128, block_n: int = 128) -> bool:
+    """Whether the kernel tiles an (M, K) x (K, N) product."""
+    bm, bn, bk = min(block_m, M), min(block_n, N), min(block, K)
+    return M % bm == 0 and N % bn == 0 and K % bk == 0
+
+
 def mxp_gemm_pallas(a, b, *, block: int = 128, block_m: int = 128,
                     block_n: int = 128, interpret: bool = False):
     M, K = a.shape
     K2, N = b.shape
     assert K == K2
     bm, bn, bk = min(block_m, M), min(block_n, N), min(block, K)
-    if M % bm or N % bn or K % bk:
+    if not mxp_gemm_fits(M, N, K, block=block, block_m=block_m,
+                         block_n=block_n):
         raise NotImplementedError("dims not divisible by block")
+    # repro-lint: divisible — guarded by mxp_gemm_fits just above
     k_steps = K // bk
     return pl.pallas_call(
         functools.partial(_mxp_kernel, k_steps=k_steps),
@@ -73,7 +82,7 @@ def mxp_gemm_pallas(a, b, *, block: int = 128, block_m: int = 128,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         # the (i, j) output tile accumulates over the k axis: sequential
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -4,29 +4,51 @@ Each op picks the Pallas TPU kernel when (a) running on TPU or (b)
 ``REPRO_FORCE_PALLAS=interpret`` (CI validation on CPU), else the pure-jnp
 reference from ``repro.kernels.ref`` — which is itself production-grade
 (flash custom-VJP etc.), so models never change semantics across backends.
+
+The path is chosen before the call, from the backend and an explicit
+shape check (``*_fits``) where a kernel tiles only some shapes.  A
+kernel that is chosen and then fails raises: nothing here catches it
+and quietly runs the reference instead.
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as _ref
+from repro.parallel.sharding import current_mesh, logical_to_spec
 
 
 def _use_pallas() -> Optional[str]:
     """None | 'tpu' | 'interpret'."""
-    env = os.environ.get("REPRO_FORCE_PALLAS", "")
-    if env in ("interpret", "1"):
+    if os.environ.get("REPRO_FORCE_PALLAS", "") in ("interpret", "1"):
         return "interpret"
-    try:
-        if jax.default_backend() == "tpu":
-            return "tpu"
-    except Exception:
-        pass
-    return None
+    return "tpu" if jax.default_backend() == "tpu" else None
+
+
+def _per_shard(kernel, q, k, v, q_pos, k_pos, window):
+    """Run a (B, S, H, d) attention kernel on each device's shard.
+
+    The compiler cannot partition a Pallas kernel, so under an active
+    mesh it runs inside ``shard_map`` over the batch and head axes the
+    activations are laid out on (their ``constrain`` names); each device
+    attends over its own rows and heads, which needs no communication.
+    """
+    window = jnp.asarray(1 << 30 if window is None else window, jnp.int32)
+    mesh = current_mesh()
+    if mesh is None:
+        return kernel(q, k, v, q_pos, k_pos, window=window)
+    heads = logical_to_spec(("batch", None, "act_heads", None), q.shape)
+    rows = logical_to_spec(("batch", None), q_pos.shape)
+    return jax.shard_map(
+        lambda q, k, v, qp, kp, w: kernel(q, k, v, qp, kp, window=w),
+        mesh=mesh, in_specs=(heads, heads, heads, rows, rows, P()),
+        out_specs=heads, check_vma=False)(q, k, v, q_pos, k_pos, window)
 
 
 def flash_attention(q, k, v, q_pos, k_pos, *, causal=True, window=None,
@@ -39,7 +61,7 @@ def flash_attention(q, k, v, q_pos, k_pos, *, causal=True, window=None,
     decode hot path) dispatch to the grouped split-KV flash-decode
     kernel, which reads each K/V cache byte exactly once; everything
     else takes the prefill/train flash path (grouped K/V expanded
-    shard-locally first).
+    shard-locally first), on the kernel where it tiles (S, T).
 
     When ``k_scale``/``v_scale`` (B, T, K) are given, k/v are a
     quantized (int8/fp8) cache and decode dispatches to the
@@ -47,23 +69,21 @@ def flash_attention(q, k, v, q_pos, k_pos, *, causal=True, window=None,
     (multi-token callers dequantize before calling).
     """
     mode = _use_pallas()
+    interpret = mode == "interpret"
     quant = k_scale is not None
     if q.shape[1] == 1:
         # decode: grouped split-KV kernel / pure-jnp twin (forward-only)
         if mode is not None:
             from repro.kernels.flash_decode import (flash_decode_pallas,
                                                     flash_decode_pallas_quant)
-            try:
-                if quant:
-                    return flash_decode_pallas_quant(
-                        q, k, v, q_pos, k_pos, k_scale, v_scale,
-                        causal=causal, window=window, softcap=softcap,
-                        interpret=(mode == "interpret"))
-                return flash_decode_pallas(
-                    q, k, v, q_pos, k_pos, causal=causal, window=window,
-                    softcap=softcap, interpret=(mode == "interpret"))
-            except NotImplementedError:
-                pass
+            if quant:
+                return flash_decode_pallas_quant(
+                    q, k, v, q_pos, k_pos, k_scale, v_scale,
+                    causal=causal, window=window, softcap=softcap,
+                    interpret=interpret)
+            return flash_decode_pallas(
+                q, k, v, q_pos, k_pos, causal=causal, window=window,
+                softcap=softcap, interpret=interpret)
         if quant:
             from repro.kernels.quant import flash_decode_quant_ref
             return flash_decode_quant_ref(
@@ -81,13 +101,13 @@ def flash_attention(q, k, v, q_pos, k_pos, *, causal=True, window=None,
         k = jnp.repeat(k, groups, axis=2)
         v = jnp.repeat(v, groups, axis=2)
     if mode is not None:
-        from repro.kernels.flash_attention import flash_attention_pallas
-        try:
-            return flash_attention_pallas(
-                q, k, v, q_pos, k_pos, causal=causal, window=window,
-                softcap=softcap, interpret=(mode == "interpret"))
-        except NotImplementedError:
-            pass
+        from repro.kernels.flash_attention import (flash_attention_fits,
+                                                   flash_attention_pallas)
+        if flash_attention_fits(q.shape[1], k.shape[1]):
+            return _per_shard(
+                functools.partial(flash_attention_pallas, causal=causal,
+                                  softcap=softcap, interpret=interpret),
+                q, k, v, q_pos, k_pos, window)
     return _ref.flash_attention_ref(q, k, v, q_pos, k_pos, causal=causal,
                                     window=window, softcap=softcap,
                                     chunk=chunk)
@@ -114,17 +134,14 @@ def flash_decode_paged(q, k_pool, v_pool, q_pos, kp_pool, block_tables, *,
     if mode is not None:
         from repro.kernels.flash_decode import (flash_decode_paged as _paged,
                                                 flash_decode_paged_quant)
-        try:
-            if quant:
-                return flash_decode_paged_quant(
-                    q, k_pool, v_pool, q_pos, kp_pool, block_tables,
-                    k_scale, v_scale, causal=causal, window=window,
-                    softcap=softcap, interpret=(mode == "interpret"))
-            return _paged(q, k_pool, v_pool, q_pos, kp_pool, block_tables,
-                          causal=causal, window=window, softcap=softcap,
-                          interpret=(mode == "interpret"))
-        except NotImplementedError:
-            pass
+        if quant:
+            return flash_decode_paged_quant(
+                q, k_pool, v_pool, q_pos, kp_pool, block_tables,
+                k_scale, v_scale, causal=causal, window=window,
+                softcap=softcap, interpret=(mode == "interpret"))
+        return _paged(q, k_pool, v_pool, q_pos, kp_pool, block_tables,
+                      causal=causal, window=window, softcap=softcap,
+                      interpret=(mode == "interpret"))
     if quant:
         from repro.kernels.quant import flash_decode_paged_quant_ref
         return flash_decode_paged_quant_ref(
@@ -135,27 +152,13 @@ def flash_decode_paged(q, k_pool, v_pool, q_pos, kp_pool, block_tables, *,
                                        window=window, softcap=softcap)
 
 
-def rmsnorm(x, scale, eps: float = 1e-6):
-    mode = _use_pallas()
-    if mode is not None:
-        from repro.kernels.rmsnorm import rmsnorm_pallas
-        try:
-            return rmsnorm_pallas(x, scale, eps=eps,
-                                  interpret=(mode == "interpret"))
-        except NotImplementedError:
-            pass
-    return _ref.rmsnorm_ref(x, scale, eps)
-
-
 def mxp_gemm(a, b, *, block: int = 128):
     mode = _use_pallas()
     if mode is not None:
-        from repro.kernels.mxp_gemm import mxp_gemm_pallas
-        try:
+        from repro.kernels.mxp_gemm import mxp_gemm_fits, mxp_gemm_pallas
+        if mxp_gemm_fits(a.shape[0], b.shape[1], a.shape[1], block=block):
             return mxp_gemm_pallas(a, b, block=block,
                                    interpret=(mode == "interpret"))
-        except NotImplementedError:
-            pass
     return _ref.mxp_gemm_ref(a, b, block=block)
 
 
@@ -172,26 +175,8 @@ def moe_gemm(xe, counts, w1, w3, w2, *, act: str = "silu"):
     """
     mode = _use_pallas()
     if mode is not None:
-        from repro.parallel.sharding import current_mesh
+        from repro.kernels.moe_gemm import moe_gemm_pallas
         if current_mesh() is None:
-            from repro.kernels.moe_gemm import moe_gemm_pallas
-            try:
-                return moe_gemm_pallas(xe, counts, w1, w3, w2, act=act,
-                                       interpret=(mode == "interpret"))
-            except NotImplementedError:
-                pass
-    return _ref.moe_gemm_ref(xe, counts, w1, w3, w2, act=act)
-
-
-def ssd_scan(x, dt, a, b, c, *, chunk: int = 256):
-    mode = _use_pallas()
-    if mode is not None:
-        from repro.kernels.ssd_scan import ssd_scan_pallas
-        try:
-            return ssd_scan_pallas(x, dt, a, b, c, chunk=chunk,
+            return moe_gemm_pallas(xe, counts, w1, w3, w2, act=act,
                                    interpret=(mode == "interpret"))
-        except NotImplementedError:
-            pass
-    from repro.models.ssm import ssd_chunked
-    y, state = ssd_chunked(x, dt, a, b, c, chunk)
-    return y, state
+    return _ref.moe_gemm_ref(xe, counts, w1, w3, w2, act=act)

@@ -108,7 +108,7 @@ def ssd_scan_pallas(x, dt, a, b, c, *, chunk: int = 256,
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         # the running state (st_ref) carries across chunks: the chunk axis
         # is a sequential scan, not a parallel dim
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xk, dtk, a.astype(jnp.float32), bk_, ck_)

@@ -1,13 +1,12 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape) cell on the
 production meshes and record memory / cost / roofline artifacts.
 
-The two lines above MUST stay the very first statements in this module:
-jax locks the device count at first initialization, and the dry-run needs
-512 placeholder CPU devices to build the (2, 16, 16) production mesh.
-Smoke tests and benchmarks import other modules and see 1 device.
+The dry-run needs 512 placeholder CPU devices to build the (2, 16, 16)
+production mesh.  Run as a script it sets them up itself, before the
+first backend use locks the device count, and pins the CPU backend so
+it never takes an accelerator another process holds.  A caller that
+imports ``run_cell`` starts its process with
+``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=512``.
 
 Usage:
     python -m repro.launch.dryrun --arch qwen3-32b --shape train_4k
@@ -17,6 +16,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import pathlib
 import sys
 import time
@@ -180,4 +180,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    jax.config.update("jax_platforms", "cpu")
     sys.exit(main())

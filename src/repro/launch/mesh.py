@@ -9,10 +9,11 @@ fabric model to be hand-threaded by every caller.  New code should use::
     plan = resolve_plan("multi-pod")        # or "single-pod" / "auto" / file
     mesh = plan.mesh()
 
-Both helpers stay FUNCTIONS (never module-level constants) so importing
+The shim stays a FUNCTION (never a module-level constant) so importing
 this module does not touch jax device state; the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import and only then builds meshes.
+import and only then builds meshes (``repro.parallel.sharding.make_mesh``
+is the one constructor).
 
 Mesh semantics (DESIGN.md §2): ``pod`` is the paper's cross-pod spine hop
 (2 pods × 8 leaf switches), ``data`` the intra-pod data-parallel/FSDP rail
@@ -21,8 +22,6 @@ group, ``model`` the tensor-parallel rail set within a node group.
 from __future__ import annotations
 
 import warnings
-from typing import Tuple
-
 import numpy as np
 from jax.sharding import Mesh
 
@@ -36,12 +35,6 @@ def make_production_mesh(*, multi_pod: bool = False):
         "plan carries the rule table and collective schedule with the mesh",
         DeprecationWarning, stacklevel=2)
     return resolve_plan("multi-pod" if multi_pod else "single-pod").mesh()
-
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """General mesh for tests / pipeline / EP experiments."""
-    import jax
-    return jax.make_mesh(shape, axes)
 
 
 def mesh_chips(mesh: Mesh) -> int:

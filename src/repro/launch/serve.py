@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced_config
+from repro.core.compile_cache import enable_compilation_cache
 from repro.core.telemetry import ServingTelemetry
 from repro.models.model import build_model
 from repro.parallel.plan import resolve_plan
@@ -77,6 +78,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compilation_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if cfg.family.value in ("encdec", "audio"):
@@ -85,7 +87,7 @@ def main(argv=None) -> int:
             "the Engine (needs src_embeds plumbing); use the launch.dryrun "
             "serve cells instead")
     model = build_model(cfg, remat="none", kv_dtype=args.kv_dtype)
-    params = model.init(jax.random.key(args.seed), dtype=jnp.float32)
+    params = model.init(jax.random.key(args.seed), dtype=jnp.bfloat16)
 
     plan = None
     if args.plan:

@@ -27,6 +27,7 @@ import sys
 import jax
 
 from repro.configs import get_config, reduced_config
+from repro.core.compile_cache import enable_compilation_cache
 from repro.core.config import (OptimizerConfig, ParallelConfig, RunConfig,
                                ShapeConfig, StepKind)
 from repro.core.telemetry import RunTelemetry
@@ -92,6 +93,7 @@ def parse_fault_spec(spec: str) -> FaultMonitor:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compilation_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     shape = ShapeConfig("train", args.seq, args.batch, StepKind.TRAIN)
@@ -115,7 +117,8 @@ def main(argv=None) -> int:
 
     pool = DevicePool(gpus_per_node=args.gpus_per_node)
     telem = RunTelemetry(args.telemetry or None, cfg, shape,
-                         n_chips=plan.chips if plan else len(jax.devices()))
+                         n_chips=plan.chips if plan else len(jax.devices()),
+                         device_kind=jax.devices()[0].device_kind)
     trainer = Trainer(
         run_cfg, plan=plan, pool=pool,
         callbacks=[LoggingCallback(every=args.log_every)],
@@ -128,7 +131,7 @@ def main(argv=None) -> int:
     report = trainer.run(args.steps)
 
     summ = telem.utilization_summary()
-    if summ:
+    if "mean_mfu" in summ:
         print(f"telemetry: mean_mfu={summ['mean_mfu']:.4f} "
               f"low_util_fraction={summ['low_util_fraction']:.2f}")
     rsum = telem.recovery_summary()

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.collectives import axis_size, shard_map_compat
-
 
 def pipeline_apply(stage_fn: Callable, params_stacked, x_micro: jax.Array,
                    *, axis: str = "pipe") -> jax.Array:
@@ -41,7 +39,7 @@ def pipeline_apply(stage_fn: Callable, params_stacked, x_micro: jax.Array,
 
     Returns (M, mb, ...) outputs as produced by the LAST stage (valid on
     every device after the final gather)."""
-    n_stage = axis_size(axis)
+    n_stage = jax.lax.axis_size(axis)
     stage = jax.lax.axis_index(axis)
     M = x_micro.shape[0]
     ticks = M + n_stage - 1
@@ -106,9 +104,9 @@ def make_pipelined_loss(mesh: Mesh, stage_fn: Callable, loss_fn: Callable,
 
     pspec = P(axis)     # stage dim sharded over pipe
     xspec = P()         # microbatches replicated
-    inner = shard_map_compat(_inner, mesh=mesh,
-                             in_specs=(pspec, xspec, xspec),
-                             out_specs=P())
+    inner = jax.shard_map(_inner, mesh=mesh,
+                          in_specs=(pspec, xspec, xspec),
+                          out_specs=P(), check_vma=False)
 
     if vp == 1:
         return inner

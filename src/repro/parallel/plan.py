@@ -72,7 +72,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from repro.core.config import CHIP, ModelConfig, SHAPES, ShapeConfig, StepKind
 from repro.core.fabric import (FABRIC, FabricSpec, dcqcn_throughput_factor)
 from repro.parallel.sharding import (Rules, _DEFAULT_RULES, logical_to_spec,
-                                     tree_shardings, use_sharding,
+                                     make_mesh, tree_shardings, use_sharding,
                                      with_overrides)
 
 GRAD_WIRE_BYTES = 4          # fp32 master gradients on the wire
@@ -569,14 +569,7 @@ class ParallelPlan:
 
     def mesh(self, devices=None):
         """Build the jax Mesh (device order: pod-spanning axis slowest)."""
-        import jax
-        import numpy as np
-        from jax.sharding import Mesh
-        if devices is not None:
-            n = int(math.prod(self.mesh_shape))
-            arr = np.asarray(devices[:n]).reshape(self.mesh_shape)
-            return Mesh(arr, self.axis_names)
-        return jax.make_mesh(self.mesh_shape, self.axis_names)
+        return make_mesh(self.mesh_shape, self.axis_names, devices)
 
     def _mesh_shape_obj(self) -> _MeshShape:
         return _MeshShape(dict(zip(self.axis_names, self.mesh_shape)))

@@ -17,12 +17,13 @@ documented fallbacks instead of lowering errors.
 from __future__ import annotations
 
 import contextlib
+import math
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 AxisCandidates = Sequence[Tuple[str, ...]]
 Rules = Dict[str, AxisCandidates]
@@ -106,6 +107,23 @@ def __getattr__(name: str):
 
 # ---------------------------------------------------------------------------
 # Ambient mesh + rules context (threaded through with_logical_constraint).
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices=None) -> Mesh:
+    """Build a device mesh — the one place this repository does.
+
+    Every axis is ``Auto``: shardings are laid out by the compiler from
+    ``constrain`` hints and the arrays' NamedShardings, never carried
+    as types.  Without ``devices`` JAX orders all its devices for the
+    physical topology; with them, the first ``prod(shape)`` keep their
+    order (a pool's node grouping depends on it).
+    """
+    auto = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=auto)
+    arr = np.asarray(list(devices)[:math.prod(shape)]).reshape(shape)
+    return Mesh(arr, tuple(axes), axis_types=auto)
+
+
 class _ShardingCtx(threading.local):
     def __init__(self):
         self.mesh: Optional[Mesh] = None

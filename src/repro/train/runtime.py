@@ -52,7 +52,7 @@ from repro.core.telemetry import RunTelemetry
 from repro.data import PackedPipeline, Prefetcher
 from repro.parallel.plan import (CollectiveSchedule, Layout, ParallelPlan,
                                  replan, score_layout)
-from repro.parallel.sharding import spec_tree_for_params
+from repro.parallel.sharding import make_mesh, spec_tree_for_params
 from repro.sched.faults import FAULT_TAXONOMY
 from repro.train.step import (abstract_train_state, init_train_state,
                               make_train_step, train_state_logical_axes)
@@ -81,9 +81,7 @@ def make_elastic_mesh(model_parallel: int, devices=None,
                       pod: Optional[int] = None) -> Mesh:
     devices = devices if devices is not None else jax.devices()
     shape, axes = shrink_data_axis(len(devices), model_parallel, pod)
-    n = int(np.prod(shape))
-    arr = np.asarray(devices[:n]).reshape(shape)
-    return Mesh(arr, axes)
+    return make_mesh(shape, axes, devices)
 
 
 def reshard_restore(mgr: CheckpointManager, abstract_state, axes_tree,
@@ -456,7 +454,10 @@ class Trainer:
             self.mesh = self.plan.mesh(devices=devs)
             self._mesh_devices = set(devs)
             self._scope.enter_context(self.plan.activate(self.mesh))
-        self.step_fn = jax.jit(make_train_step(self.model, self.run_cfg))
+        # the state is donated: the step's new state reuses its buffers,
+        # so device memory holds one copy of params + optimizer state
+        self.step_fn = jax.jit(make_train_step(self.model, self.run_cfg),
+                               donate_argnums=0)
 
     def _shard_state(self, state):
         if self.plan is None:

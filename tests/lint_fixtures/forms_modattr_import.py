@@ -1,13 +1,13 @@
 """Binding-form regression: ``import jax.experimental.pallas as pl``
 (plain module import with asname, not ``from ... import``).  The
 semantic rules must still resolve the call site — proven by the RL007
-bug being found through it.  Also exercises the legacy dict-form
-``compiler_params`` spelling."""
+bug being found through it."""
 import os
 
 import jax
 import jax.numpy as jnp
 import jax.experimental.pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
 
 def _interpret() -> bool:
@@ -28,7 +28,7 @@ def running_sum(x):
         in_specs=[pl.BlockSpec((half, cols), lambda si: (si, 0))],
         out_specs=pl.BlockSpec((half, cols), lambda si: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((half, cols), x.dtype),
-        compiler_params=dict(mosaic=dict(
-            dimension_semantics=("arbitrary",))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(x)

@@ -18,6 +18,6 @@ def running_sum(x):
         in_specs=[pl.BlockSpec((half, cols), lambda si: (si, 0))],
         out_specs=pl.BlockSpec((half, cols), lambda si: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((half, cols), x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(x)

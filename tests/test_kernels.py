@@ -92,6 +92,52 @@ def test_ops_flash_attention_softcap_uses_pallas(monkeypatch):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
+def test_ops_flash_attention_shape_check(monkeypatch):
+    """The kernel-or-twin choice is an explicit shape check made before
+    the call: lengths the 512 blocks do not tile take the jnp twin."""
+    from repro.kernels import flash_attention as fa_mod
+    from repro.kernels import ops
+    assert fa_mod.flash_attention_fits(64, 64)          # one whole block
+    assert fa_mod.flash_attention_fits(1024, 4096)
+    assert not fa_mod.flash_attention_fits(520, 520)
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "interpret")
+    called = []
+    monkeypatch.setattr(fa_mod, "flash_attention_pallas",
+                        lambda *a, **kw: called.append(1))
+    B, S, H, d = 1, 520, 1, 16
+    q, k, v = _qkv(B, S, H, d, jnp.float32, seed=7)
+    qp = jnp.broadcast_to(jnp.arange(S), (B, S))
+    got = ops.flash_attention(q, k, v, qp, qp, chunk=520)
+    assert not called
+    want = attention_oracle(q, k, v, qp, qp)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("softcap", [None, 20.0])
+def test_flash_pallas_grads_vs_ref(softcap):
+    """The kernel's custom VJP (kernel forward, recompute backward) gives
+    the gradients of the jnp twin."""
+    B, S, H, d = 2, 64, 2, 16
+    q, k, v = _qkv(B, S, H, d, jnp.float32, seed=8)
+    qp = jnp.broadcast_to(jnp.arange(S), (B, S))
+    kp = qp.at[1, 40:].set(-1)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    pallas = lambda q, k, v: flash_attention_pallas(
+        q, k, v, qp, kp, softcap=softcap, block_q=16, block_k=16,
+        interpret=True)
+    ref = lambda q, k, v: flash_attention_ref(q, k, v, qp, kp,
+                                              softcap=softcap, chunk=16)
+    got = jax.grad(loss(pallas), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=TOL[jnp.float32],
+                                   rtol=TOL[jnp.float32])
+
+
 @pytest.mark.parametrize("softcap", [None, 20.0])
 @pytest.mark.parametrize("window", [None, 24])
 def test_flash_ref_grads_vs_oracle(softcap, window):

@@ -41,7 +41,10 @@ def _page_cache(k, v, kp, BS, seed, extra_blocks=3):
     nb = T // BS
     NB = B * nb + extra_blocks
     rng = np.random.default_rng(seed)
-    perm = rng.permutation(NB)[:B * nb].reshape(B, nb)
+    perm = rng.permutation(NB)[:B * nb]
+    if np.array_equal(perm, np.arange(B * nb)):
+        perm = (perm + 1) % NB          # a draw can be the identity
+    perm = perm.reshape(B, nb)
     k_pool = np.zeros((NB, BS, K, d), np.float32)
     v_pool = np.zeros((NB, BS, K, d), np.float32)
     kp_pool = np.full((NB, BS), -1, np.int32)

@@ -271,6 +271,37 @@ def test_telemetry_records_recovery(cfg, shape, tmp_path):
     assert telem.utilization_summary()["steps"] == 1
 
 
+def test_telemetry_mfu_keyed_by_device_kind(cfg, shape):
+    """MFU divides by the published peak of the device kind the run is
+    on; a kind the peaks table lacks reports no MFU at all."""
+    v5e = RunTelemetry(None, cfg, shape, device_kind="TPU v5 lite")
+    assert v5e.step(0, {"loss": 1.0})["mfu"] > 0
+    assert "mean_mfu" in v5e.utilization_summary()
+    cpu = RunTelemetry(None, cfg, shape, device_kind="cpu")
+    assert cpu.step(0, {"loss": 1.0})["mfu"] is None
+    assert cpu.utilization_summary() == {"steps": 1}
+
+
+def test_compilation_cache_dir(monkeypatch, tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR where set, else <checkout>/.jax_cache,
+    which git ignores."""
+    from repro.core import compile_cache
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compilation_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        default = compile_cache.CHECKOUT / ".jax_cache"
+        assert compile_cache.enable_compilation_cache() == str(default)
+        assert jax.config.jax_compilation_cache_dir == str(default)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
+    assert (compile_cache.CHECKOUT / "src" / "repro").is_dir()
+    ignored = (compile_cache.CHECKOUT / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
+
+
 def test_trainer_emits_recovery_telemetry(cfg, shape, tmp_path):
     telem = RunTelemetry(None, cfg, shape, n_chips=1)
     tr = Trainer(_run_cfg(cfg, shape, steps=6), ckpt_dir=str(tmp_path),
