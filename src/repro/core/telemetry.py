@@ -6,7 +6,9 @@ tokens/s, MFU against the published peak of the device kind it ran on,
 None where that kind has no entry) — the signals the paper's
 practitioners watched to decide the cancellations that dominate
 Observation 1 — plus utilization summaries compatible with the cluster
-simulator's per-job records (Observation 3's methodology).
+simulator's per-job records (Observation 3's methodology).  ``span``
+marks the host phases of a serving tick or a train step in the JAX
+profiler's trace, on the clock of the device's ops.
 """
 from __future__ import annotations
 
@@ -15,7 +17,19 @@ import pathlib
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.config import ModelConfig, ShapeConfig, chip_peaks
+
+
+def span(name: str, **attrs) -> TraceAnnotation:
+    """A host span ``name`` in the JAX profiler's trace, with ``attrs``
+    as its arguments.  ``Engine`` and ``Trainer`` put them around the
+    phases of a tick and of a step (``engine.*``, ``trainer.*``; a name
+    ending in ``_sync`` is one in which the host waits on the device).
+    Nearly free when no trace is being taken: about a microsecond, no
+    device work and no sync."""
+    return TraceAnnotation(name, **attrs)
 
 
 class RunTelemetry:

@@ -134,6 +134,7 @@ def _flash_call(static, q, k, v, q_pos, k_pos, window):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(window, q_pos.astype(jnp.int32).reshape(B, 1, S),
       k_pos.astype(jnp.int32).reshape(B, 1, T), qt, kt, vt)
     return jnp.swapaxes(out, 1, 2), lse[:, :, 0]

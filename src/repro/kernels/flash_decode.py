@@ -180,6 +180,7 @@ def _decode_call(q, k, v, k_pos, q_pos, window, *, block, splits,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="flash_decode" + "_paged" * paged + "_quant" * quant,
     )(*prefetch, *operands)
 
     out = combine_partials(o_part, m_part, l_part)         # (B, K, G, d)

@@ -126,6 +126,7 @@ def _moe_gemm_call(xe, counts, w1, w3, w2, *, act: str, bm: int, bf: int,
             vmem_limit_bytes=_vmem_limit(bm, bf, D, xe.dtype.itemsize,
                                          w1.dtype.itemsize)),
         interpret=interpret,
+        name="moe_gemm",
     )(expert_ids, block_valid, xr, w1, w3, w2)
     return out.reshape(B, E, C, D)[:, :, :C0]
 

@@ -85,4 +85,5 @@ def mxp_gemm_pallas(a, b, *, block: int = 128, block_m: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="mxp_gemm",
     )(a, b)

@@ -41,5 +41,6 @@ def rmsnorm_pallas(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
         out_specs=pl.BlockSpec((rb, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, D), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(xf, scale)
     return out.reshape(orig_shape)

@@ -111,6 +111,7 @@ def ssd_scan_pallas(x, dt, a, b, c, *, chunk: int = 256,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_scan",
     )(xk, dtk, a.astype(jnp.float32), bk_, ck_)
 
     y = y.reshape(B, H, S, P).transpose(0, 2, 1, 3)

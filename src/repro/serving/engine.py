@@ -20,6 +20,14 @@ slots masked in-kernel.  Prefill compiles once per prompt-length
 bucket (``prefill_chunk`` rounds lengths up; pure-global-attention archs
 only — ring buffers and SSM state cannot mask pad tokens).
 
+Tracing: each tick's phases are host spans in the JAX profiler's trace
+(``repro.core.telemetry.span``), on the thread that calls ``step``:
+``engine.admit`` per admission (args ``rid``, ``tokens``) holding
+``engine.prefill`` and ``engine.first_token_sync``, then
+``engine.prepare``, ``engine.decode`` (the dispatch),
+``engine.decode_sync`` (the wait for the tick's tokens) and
+``engine.emit``.  They add no device work and no sync.
+
 Legacy API: ``make_prefill_step`` / ``make_decode_step`` are the
 original greedy step factories, kept as deprecated shims (the dry-run
 cells still lower them); ``repro.serving.batcher.ContinuousBatcher``
@@ -37,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.config import Family
-from repro.core.telemetry import ServingTelemetry
+from repro.core.telemetry import ServingTelemetry, span
 from repro.models.lm import window_layout
 from repro.serving.request import (GenerationResult, InferenceRequest,
                                    RequestState, TokenCallback)
@@ -268,29 +276,37 @@ class Engine:
         return S
 
     def _join(self, slot: int, req: InferenceRequest):
-        """Prefill at batch=1, sample the first token, scatter into slot."""
+        """Admit one request: prefill it, sample its first token, arm
+        its slot (the ``engine.admit`` span; ``t_admitted`` closes it)."""
         req.state = RequestState.PREFILL
         req.metrics.t_prefill_start = self.clock()
         S = int(min(len(req.prompt), self.prefill_len))
-        if self.paged:
-            self._join_paged(slot, req, S)
-            return
-        Sp = self._bucket_len(S)
-        toks = np.zeros(Sp, np.int32)
-        toks[:S] = req.prompt[:S]
-        pos = np.arange(Sp, dtype=np.int32)
-        pos[S:] = -1                      # pads: masked keys, no-op RoPE
-        batch: Dict[str, Any] = {"tokens": jnp.asarray(toks)[None]}
-        if self.cfg.m_rope_sections is not None:
-            batch["positions"] = jnp.broadcast_to(
-                jnp.asarray(pos)[None, None], (3, 1, Sp))
-        elif Sp != S:
-            batch["positions"] = jnp.asarray(pos)[None]
-        if Sp != S:
-            batch["length"] = jnp.asarray([S], jnp.int32)
-        with self._scope():
-            logits, cache1 = self._prefill(self.params, batch)
-        self.cache = self.pool.scatter_prefill(self.cache, cache1, slot)
+        with span("engine.admit", rid=req.rid, tokens=S):
+            if self.paged:
+                self._join_paged(slot, req, S)
+            else:
+                self._join_contiguous(slot, req, S)
+        req.metrics.t_admitted = self.clock()
+
+    def _join_contiguous(self, slot: int, req: InferenceRequest, S: int):
+        """Prefill at batch=1, scatter into the slot's cache row."""
+        with span("engine.prefill"):
+            Sp = self._bucket_len(S)
+            toks = np.zeros(Sp, np.int32)
+            toks[:S] = req.prompt[:S]
+            pos = np.arange(Sp, dtype=np.int32)
+            pos[S:] = -1                  # pads: masked keys, no-op RoPE
+            batch: Dict[str, Any] = {"tokens": jnp.asarray(toks)[None]}
+            if self.cfg.m_rope_sections is not None:
+                batch["positions"] = jnp.broadcast_to(
+                    jnp.asarray(pos)[None, None], (3, 1, Sp))
+            elif Sp != S:
+                batch["positions"] = jnp.asarray(pos)[None]
+            if Sp != S:
+                batch["length"] = jnp.asarray([S], jnp.int32)
+            with self._scope():
+                logits, cache1 = self._prefill(self.params, batch)
+            self.cache = self.pool.scatter_prefill(self.cache, cache1, slot)
         self.pool.acquire(slot, req.rid, S)
         req.metrics.prefilled_tokens = S
         self._finish_join(slot, req, logits)
@@ -302,21 +318,22 @@ class Engine:
         cached = self.pool.acquire_blocks(slot, req.rid, prompt,
                                           req.sampling.max_new_tokens)
         Ssuf = S - cached
-        Sp = self._bucket_len(Ssuf)
-        toks = np.zeros(Sp, np.int32)
-        toks[:Ssuf] = prompt[cached:]
-        pos = np.arange(Sp, dtype=np.int32) + cached
-        pos[Ssuf:] = -1                   # pads: dropped writes, dead keys
-        batch: Dict[str, Any] = {
-            "tokens": jnp.asarray(toks)[None],
-            "positions": jnp.asarray(pos)[None],
-            "length": jnp.asarray([Ssuf], jnp.int32),
-            "block_tables": jnp.asarray(
-                self.pool.block_tables[slot:slot + 1]),
-        }
-        with self._scope():
-            logits, self.cache = self._prefix_prefill(self.params, batch,
-                                                      self.cache)
+        with span("engine.prefill"):
+            Sp = self._bucket_len(Ssuf)
+            toks = np.zeros(Sp, np.int32)
+            toks[:Ssuf] = prompt[cached:]
+            pos = np.arange(Sp, dtype=np.int32) + cached
+            pos[Ssuf:] = -1               # pads: dropped writes, dead keys
+            batch: Dict[str, Any] = {
+                "tokens": jnp.asarray(toks)[None],
+                "positions": jnp.asarray(pos)[None],
+                "length": jnp.asarray([Ssuf], jnp.int32),
+                "block_tables": jnp.asarray(
+                    self.pool.block_tables[slot:slot + 1]),
+            }
+            with self._scope():
+                logits, self.cache = self._prefix_prefill(
+                    self.params, batch, self.cache)
         self.pool.register_prefix(slot, prompt)
         req.metrics.prefix_cached_tokens = cached
         req.metrics.prefilled_tokens = Ssuf
@@ -325,15 +342,16 @@ class Engine:
     def _finish_join(self, slot: int, req: InferenceRequest, logits):
         """Shared join tail: sample token 0, arm the slot's decode state."""
         sp = req.sampling
-        first = self._sample1(
-            logits,
-            jnp.asarray([sp.seed & 0xFFFFFFFF], jnp.uint32),
-            jnp.asarray([0], jnp.int32),
-            jnp.asarray([sp.temperature], jnp.float32),
-            jnp.asarray([sp.top_k], jnp.int32),
-            jnp.asarray([sp.top_p], jnp.float32))
+        with span("engine.first_token_sync"):
+            first = self._sample1(
+                logits,
+                jnp.asarray([sp.seed & 0xFFFFFFFF], jnp.uint32),
+                jnp.asarray([0], jnp.int32),
+                jnp.asarray([sp.temperature], jnp.float32),
+                jnp.asarray([sp.top_k], jnp.int32),
+                jnp.asarray([sp.top_p], jnp.float32))
+            tok = int(first[0])
         self._slot_req[slot] = req
-        tok = int(first[0])
         self.last_tok[slot] = tok
         self._temp[slot] = sp.temperature
         self._top_k[slot] = sp.top_k
@@ -438,40 +456,43 @@ class Engine:
             admitted += 1
         if self.pool.num_active == 0:
             return admitted > 0
-        if self.paged:
-            # map the block holding each active row's next write
-            # position before the tick (draws on admission reservations)
+        with span("engine.prepare"):
+            if self.paged:
+                # map the block holding each active row's next write
+                # position before the tick (draws on admission
+                # reservations)
+                for slot in range(self.slots):
+                    if self._slot_req[slot] is not None:
+                        self.pool.ensure_block(slot)
+            self.cache["len"] = jnp.asarray(int(self.pool.lengths.max()),
+                                            jnp.int32)
+            extra = ({"block_tables": jnp.asarray(self.pool.block_tables)}
+                     if self.paged else {})
+            with self._scope():
+                args = [jnp.asarray(a) for a in (
+                    self.last_tok, self.pool.positions(), self._seeds,
+                    self._steps, self._temp, self._top_k, self._top_p)]
+        with span("engine.decode"), self._scope():
+            tok, self.cache = self._generate(self.params, self.cache, *args,
+                                             **extra)
+        with span("engine.decode_sync"):
+            tok_host = np.asarray(jax.block_until_ready(tok))
+        with span("engine.emit"):
+            self.last_tok = tok_host.copy()
+            self.ticks += 1
             for slot in range(self.slots):
-                if self._slot_req[slot] is not None:
-                    self.pool.ensure_block(slot)
-        self.cache["len"] = jnp.asarray(int(self.pool.lengths.max()),
-                                        jnp.int32)
-        extra = ({"block_tables": jnp.asarray(self.pool.block_tables)}
-                 if self.paged else {})
-        with self._scope():
-            tok, self.cache = self._generate(
-                self.params, self.cache,
-                jnp.asarray(self.last_tok),
-                jnp.asarray(self.pool.positions()),
-                jnp.asarray(self._seeds), jnp.asarray(self._steps),
-                jnp.asarray(self._temp), jnp.asarray(self._top_k),
-                jnp.asarray(self._top_p), **extra)
-        tok_host = np.asarray(jax.block_until_ready(tok))
-        self.last_tok = tok_host.copy()
-        self.ticks += 1
-        for slot in range(self.slots):
-            # read live, not a snapshot: an on_token callback earlier in
-            # this loop may have cancel()ed a later slot's request
-            req = self._slot_req[slot]
-            if req is None or req.state.is_terminal:
-                continue
-            t = int(tok_host[slot])
-            self.pool.advance(slot)
-            self._steps[slot] += 1
-            last = self._is_last(req, t) or self._at_capacity(slot)
-            req.emit(t, last)
-            if last and self._slot_req[slot] is req:
-                self._retire(slot)
+                # read live, not a snapshot: an on_token callback earlier
+                # in this loop may have cancel()ed a later slot's request
+                req = self._slot_req[slot]
+                if req is None or req.state.is_terminal:
+                    continue
+                t = int(tok_host[slot])
+                self.pool.advance(slot)
+                self._steps[slot] += 1
+                last = self._is_last(req, t) or self._at_capacity(slot)
+                req.emit(t, last)
+                if last and self._slot_req[slot] is req:
+                    self._retire(slot)
         return True
 
     def run(self, max_ticks: int = 1000) -> Dict[int, GenerationResult]:

@@ -6,6 +6,8 @@ transition and derives the serving metrics the load benchmark and
 ``repro.core.telemetry.ServingTelemetry`` aggregate:
 
     queue_wait  time from submit to prefill start
+    admit_time  time the engine spent admitting it (prefill start ->
+                its slot armed: the ``engine.admit`` span)
     ttft        time to first token (submit -> first sampled token)
     tpot        time per output token over the decode phase
 """
@@ -40,6 +42,7 @@ class RequestMetrics:
     t_submit: float = 0.0
     t_prefill_start: Optional[float] = None
     t_first_token: Optional[float] = None
+    t_admitted: Optional[float] = None
     t_finish: Optional[float] = None
     prompt_tokens: int = 0
     output_tokens: int = 0
@@ -57,6 +60,12 @@ class RequestMetrics:
         if self.t_prefill_start is None:
             return None
         return self.t_prefill_start - self.t_submit
+
+    @property
+    def admit_time(self) -> Optional[float]:
+        if self.t_admitted is None or self.t_prefill_start is None:
+            return None
+        return self.t_admitted - self.t_prefill_start
 
     @property
     def ttft(self) -> Optional[float]:

@@ -25,6 +25,13 @@ monolithic ``launch.train`` script into that loop:
     shrinks the data axis while preserving TP groups
     (:func:`shrink_data_axis`).
 
+Tracing: each step's phases are host spans in the JAX profiler's trace
+(``repro.core.telemetry.span``), on the thread that calls ``run`` (none
+in the prefetch thread): ``trainer.data`` (the wait on the prefetcher),
+``trainer.put``, ``trainer.step`` (the dispatch), ``trainer.loss_sync``
+(the wait for the step's loss), ``trainer.callbacks`` and
+``trainer.checkpoint``.
+
 Checkpoints are stored shard-agnostically (full logical arrays per leaf),
 so restoring onto a different mesh is just load + device_put with the new
 NamedShardings (:func:`reshard_restore`).  ``launch.elastic`` is now a
@@ -48,7 +55,7 @@ from jax.sharding import Mesh
 from repro.checkpoint import CheckpointManager
 from repro.core.config import RunConfig
 from repro.core.fabric import FABRIC, FabricSpec
-from repro.core.telemetry import RunTelemetry
+from repro.core.telemetry import RunTelemetry, span
 from repro.data import PackedPipeline, Prefetcher
 from repro.parallel.plan import (CollectiveSchedule, Layout, ParallelPlan,
                                  replan, score_layout)
@@ -640,14 +647,19 @@ class Trainer:
                     if self.state == RunnerState.RUNNING:
                         self._transition(RunnerState.DRAINING)
 
-                batch, pipe_state = next(self._it)
-                batch = {k: jnp.asarray(v) for k, v in batch.items()}
-                self.train_state, metrics = self.step_fn(self.train_state,
-                                                         batch)
+                with span("trainer.data"):
+                    batch, pipe_state = next(self._it)
+                with span("trainer.put"):
+                    batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                with span("trainer.step"):
+                    self.train_state, metrics = self.step_fn(
+                        self.train_state, batch)
                 self._pipe_state = pipe_state
-                losses.append(float(metrics["loss"]))
-                for cb in self.callbacks:
-                    cb.on_step(self, step, metrics)
+                with span("trainer.loss_sync"):
+                    losses.append(float(metrics["loss"]))
+                with span("trainer.callbacks"):
+                    for cb in self.callbacks:
+                        cb.on_step(self, step, metrics)
 
                 boundary = (step + 1) % self.ckpt_every == 0
                 done = step + 1 >= self.total_steps
@@ -655,8 +667,10 @@ class Trainer:
                     # drain barrier: blocking checkpoint, then recover with
                     # zero lost steps
                     if self.mgr is not None:
-                        self.mgr.drain(step + 1, self.train_state,
-                                       extra={"pipeline": self._pipe_state})
+                        with span("trainer.checkpoint"):
+                            self.mgr.drain(
+                                step + 1, self.train_state,
+                                extra={"pipeline": self._pipe_state})
                     events, self._pending = self._pending, []
                     if done:
                         # nothing left to resume onto — the drain
@@ -666,9 +680,10 @@ class Trainer:
                     step = self._recover(step + 1, events, drained=True)
                     continue
                 if self.mgr is not None and boundary:
-                    self.mgr.save(step + 1, self.train_state,
-                                  extra={"pipeline": self._pipe_state},
-                                  blocking=False)
+                    with span("trainer.checkpoint"):
+                        self.mgr.save(step + 1, self.train_state,
+                                      extra={"pipeline": self._pipe_state},
+                                      blocking=False)
                 step += 1
         except Exception:
             if self.state != RunnerState.FAILED:
