@@ -32,7 +32,16 @@ it.  Positions ride as ``(rows, 1, tokens)`` so their blocks are
 ``(1, block)`` rows, and the per-row query position and the window are
 scalar-prefetch operands in SMEM.  On a TPU the block (``block_k``, or
 the pool's ``block_size``) must be a multiple of the sublane tile:
-8 rows for f32, 16 for bf16, 32 for int8/fp8.
+8 rows for f32, 16 for bf16, 32 for int8/fp8; a contiguous row's block
+is also the lane width of its position row, so a multiple of 128.
+
+In place.  The contiguous kernels read a decode loop's stacked
+``(layers, B, T, K, d)`` cache directly: the layer index is one more
+scalar-prefetch operand and the K/V, position and scale index maps
+select that layer, so no layer is copied out for the call.  Nor is the
+cache padded to whole blocks: the block is the largest lane-multiple
+divisor of the row length up to ``block_k``, and only a row length
+with none gets a ragged last block, masked in-kernel by column index.
 
 Masking is position-based, identical to the prefill kernel: ``k_pos``
 is ``(B, T)`` int32 with -1 marking empty ring-buffer slots, ``q_pos``
@@ -48,7 +57,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,33 +69,39 @@ NEG_INF = -1e30
 
 def _decode_kernel(*refs, kv_heads: int, scale: float,
                    causal: bool, softcap: Optional[float], paged: bool,
-                   quant: bool):
+                   quant: bool, tokens: Optional[int]):
     """One (batch row × kv block) program over every kv head.
 
-    Scalar prefetch: [block table (paged)], window (1,), q_pos (B,).
-    Blocks: q (1, K, G, d); k/v (1, bk, K, d); k_pos (1, 1, bk);
-    [k/v scales (1, bk, K)].  Emits the block's partial (acc, m, l)
-    per kv head; no cross-program state.  A paged table entry of -1
-    kills the whole block's columns, so the pool block it was clamped
-    to — which may belong to another request — contributes nothing.
+    Scalar prefetch: block table (paged) or layer index (contiguous,
+    read by the index maps only), window (1,), q_pos (B,).  Blocks:
+    q (1, K, G, d); k/v (1, bk, K, d); k_pos (1, 1, bk); [k/v scales
+    (1, bk, K)].  Emits the block's partial (acc, m, l) per kv head; no
+    cross-program state.  A paged table entry of -1 kills the whole
+    block's columns, so the pool block it was clamped to — which may
+    belong to another request — contributes nothing.  ``tokens`` is the
+    row length when the last block runs past it: its columns beyond
+    are masked, and their V (unspecified memory) zeroed.
     """
-    if paged:
-        bt_ref, win_ref, qpos_ref, *refs = refs
-    else:
-        win_ref, qpos_ref, *refs = refs
+    idx_ref, win_ref, qpos_ref, *refs = refs
     if quant:
         q_ref, k_ref, v_ref, kp_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref = refs
     else:
         q_ref, k_ref, v_ref, kp_ref, o_ref, m_ref, l_ref = refs
-    b = pl.program_id(0)
+    b, si = pl.program_id(0), pl.program_id(1)
     qp = qpos_ref[b]                       # scalar: this row's position
     kp = kp_ref[0]                         # (1, bk)
     valid = kp >= 0
     if paged:
-        valid &= bt_ref[b, pl.program_id(1)] >= 0
+        valid &= idx_ref[b, si] >= 0
     if causal:
         valid &= qp >= kp
     valid &= (qp - kp) < win_ref[0]
+    if tokens is not None:
+        bk = kp.shape[-1]
+        col = si * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        valid &= col < tokens
+        row_ok = (si * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+                  < tokens)
 
     for h in range(kv_heads):
         q = q_ref[0, h]                    # (G, d)
@@ -98,6 +113,8 @@ def _decode_kernel(*refs, kv_heads: int, scale: float,
             q = q.astype(jnp.float32)
             k = k.astype(jnp.float32) * ks_ref[0, :, h:h + 1]
             v = v.astype(jnp.float32) * vs_ref[0, :, h:h + 1]
+        if tokens is not None:
+            v = jnp.where(row_ok, v, jnp.zeros_like(v))
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # (G, bk)
@@ -118,48 +135,51 @@ def _decode_kernel(*refs, kv_heads: int, scale: float,
         l_ref[0, 0, h] = l
 
 
-def _decode_call(q, k, v, k_pos, q_pos, window, *, block, splits,
-                 kv_map, scale_map, pos_map, scales=None, block_tables=None,
+def _decode_call(q, k, v, k_pos, q_pos, window, index, *, block, splits,
+                 kv_map, scale_map, pos_map, paged, scales=None, tokens=None,
                  causal, softcap, interpret):
     """Shared pallas_call for the four decode variants.
 
-    k, v: (rows, tokens, K, d) — a contiguous cache (rows = B) or a
-    pool (rows = num_blocks); k_pos: (rows, 1, tokens); scales: None
-    or (k_scale, v_scale), each (rows, tokens, K).  ``kv_map``,
-    ``scale_map`` and ``pos_map`` are the index maps of the K/V, scale
-    and position blocks over the (B, splits) grid and the
-    scalar-prefetch refs.
+    k, v: (L, B, tokens, K, d) — a contiguous cache stacked over layers
+    — or (num_blocks, tokens, K, d), a pool; k_pos and scales have the
+    same leading dims, as (..., 1, tokens) and (..., tokens, K).  The
+    stack's layer dim is squeezed out of every block, so the kernel
+    sees the same (1, block, ...) blocks either way.  ``index`` is the
+    first scalar-prefetch operand: the block table (paged) or the layer
+    (1,).  ``kv_map``, ``scale_map`` and ``pos_map`` are the index maps
+    of the K/V, scale and position blocks over the (B, splits) grid and
+    the scalar-prefetch refs.
     """
-    # repro-lint: divisible — the contiguous wrapper pads the cache to
-    # whole blocks, and a pool is whole blocks by construction
+    # repro-lint: divisible — a contiguous block divides the row or the
+    # kernel masks the ragged tail (``tokens``); a pool is whole blocks
     B, _, H, d = q.shape
-    K = k.shape[2]
+    K = k.shape[-2]
     G = H // K
-    paged = block_tables is not None
     quant = scales is not None
     if window is None:
         window = 1 << 30
     window = jnp.asarray(window, jnp.int32).reshape(1)
     qp = q_pos.astype(jnp.int32).reshape(B, -1)[:, 0]
-    prefetch = ((block_tables.astype(jnp.int32),) if paged else ()) + (
-        window, qp)
+    prefetch = (index.astype(jnp.int32), window, qp)
+    lead = (None,) * (k.ndim - 4)
 
     q_map = lambda b, si, *_: (b, 0, 0, 0)
     in_specs = [
         pl.BlockSpec((1, K, G, d), q_map),
-        pl.BlockSpec((1, block, K, d), kv_map),
-        pl.BlockSpec((1, block, K, d), kv_map),
-        pl.BlockSpec((1, 1, block), pos_map),
+        pl.BlockSpec(lead + (1, block, K, d), kv_map),
+        pl.BlockSpec(lead + (1, block, K, d), kv_map),
+        pl.BlockSpec(lead + (1, 1, block), pos_map),
     ]
     operands = [q[:, 0].reshape(B, K, G, d), k, v, k_pos.astype(jnp.int32)]
     if quant:
-        in_specs += [pl.BlockSpec((1, block, K), scale_map)] * 2
+        in_specs += [pl.BlockSpec(lead + (1, block, K), scale_map)] * 2
         operands += [s.astype(jnp.float32) for s in scales]
     out_map = lambda b, si, *_: (b, si, 0, 0, 0)
     o_part, m_part, l_part = pl.pallas_call(
         functools.partial(_decode_kernel, kv_heads=K,
                           scale=1.0 / math.sqrt(d), causal=causal,
-                          softcap=softcap, paged=paged, quant=quant),
+                          softcap=softcap, paged=paged, quant=quant,
+                          tokens=tokens),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(B, splits),
@@ -196,29 +216,49 @@ def _check_decode(q, K):
         raise NotImplementedError(f"q heads {H} not grouped over kv {K}")
 
 
-def _contiguous(q, k, v, q_pos, k_pos, scales, *, causal, window, softcap,
-                block_k, interpret):
-    B, T, K, d = k.shape
-    _check_decode(q, K)
-    bk = min(block_k, T)
-    if T % bk:
-        # pad the tail block with masked columns (k_pos = -1 marks them
-        # empty) so odd cache lengths work; padded K/V are zeros and are
-        # never read through the position mask
-        pad = ((0, 0), (0, bk - T % bk))
-        k = jnp.pad(k, pad + ((0, 0), (0, 0)))
-        v = jnp.pad(v, pad + ((0, 0), (0, 0)))
-        k_pos = jnp.pad(k_pos, pad, constant_values=-1)
+LANES = 128     # a contiguous block is the lane width of its position row
+
+
+def contiguous_block(T: int, block_k: int) -> Tuple[int, bool]:
+    """(block, ragged) for a contiguous row of ``T`` tokens.
+
+    A row that fits in ``block_k`` is one block.  Otherwise the block is
+    the largest divisor of ``T`` that is a multiple of the lane width
+    (of ``block_k`` itself when that is smaller, as in interpret-mode
+    tests) and at most ``block_k``; only where none exists is the block
+    the largest such multiple, the grid runs ``cdiv(T, block)`` and the
+    last block is ragged (masked in-kernel).  1,280 -> 256, 5,120 -> 512
+    at the default ``block_k`` of 512.
+    """
+    if T <= block_k:
+        return T, False
+    step = min(LANES, block_k)
+    top = block_k - block_k % step
+    for bk in range(top, 0, -step):
+        if T % bk == 0:
+            return bk, False
+    return top, True
+
+
+def _contiguous(q, k, v, q_pos, k_pos, scales, layer, *, causal, window,
+                softcap, block_k, interpret):
+    if layer is None:             # one layer's cache: a stack of one
+        k, v, k_pos = k[None], v[None], k_pos[None]
         if scales is not None:
-            scales = tuple(jnp.pad(s, pad + ((0, 0),)) for s in scales)
-        T = k.shape[1]
+            scales = tuple(s[None] for s in scales)
+        layer = 0
+    Lr, B, T, K, _ = k.shape
+    _check_decode(q, K)
+    bk, ragged = contiguous_block(T, block_k)
     return _decode_call(
-        q, k, v,
-        k_pos.reshape(B, 1, T), q_pos, window, block=bk, splits=T // bk,
-        kv_map=lambda b, si, *_: (b, si, 0, 0),
-        scale_map=lambda b, si, *_: (b, si, 0),
-        pos_map=lambda b, si, *_: (b, 0, si),
-        scales=scales, causal=causal, softcap=softcap, interpret=interpret)
+        q, k, v, k_pos.reshape(Lr, B, 1, T), q_pos, window,
+        jnp.asarray(layer, jnp.int32).reshape(1), block=bk,
+        splits=-(-T // bk),
+        kv_map=lambda b, si, lyr, *_: (lyr[0], b, si, 0, 0),
+        scale_map=lambda b, si, lyr, *_: (lyr[0], b, si, 0),
+        pos_map=lambda b, si, lyr, *_: (lyr[0], b, 0, si),
+        paged=False, scales=scales, tokens=T if ragged else None,
+        causal=causal, softcap=softcap, interpret=interpret)
 
 
 def _paged(q, k_pool, v_pool, q_pos, kp_pool, block_tables, scales, *,
@@ -230,26 +270,30 @@ def _paged(q, k_pool, v_pool, q_pos, kp_pool, block_tables, scales, *,
     blk = lambda b, si, bt, *_: jnp.maximum(bt[b, si], 0)
     return _decode_call(
         q, k_pool, v_pool,
-        kp_pool.reshape(NB, 1, BS), q_pos, window, block=BS,
+        kp_pool.reshape(NB, 1, BS), q_pos, window, block_tables, block=BS,
         splits=block_tables.shape[1],
         kv_map=lambda b, si, *r: (blk(b, si, *r), 0, 0, 0),
         scale_map=lambda b, si, *r: (blk(b, si, *r), 0, 0),
         pos_map=lambda b, si, *r: (blk(b, si, *r), 0, 0),
-        scales=scales, block_tables=block_tables, causal=causal,
-        softcap=softcap, interpret=interpret)
+        paged=True, scales=scales, causal=causal, softcap=softcap,
+        interpret=interpret)
 
 
 def flash_decode_pallas(q, k, v, q_pos, k_pos, *, causal: bool = True,
                         window=None, softcap: Optional[float] = None,
-                        block_k: int = 512, interpret: bool = False):
+                        block_k: int = 512, interpret: bool = False,
+                        layer=None):
     """Grouped split-KV flash decode over a contiguous cache.
 
     q: (B, 1, H, d) — ONE query token per row; k, v: (B, T, K, d) at the
     native kv-head count (H % K == 0, no repeat); q_pos: (B, 1) or (B,);
     k_pos: (B, T) int32 with -1 = empty slot.  ``window`` may be None,
-    an int, or a traced scalar.  Returns (B, 1, H, d).
+    an int, or a traced scalar.  With ``layer`` (an int or a traced
+    scalar), k, v are the stacked (L, B, T, K, d) cache of every layer
+    and k_pos (L, B, T): the kernel reads that layer in place.  Returns
+    (B, 1, H, d).
     """
-    return _contiguous(q, k, v, q_pos, k_pos, None, causal=causal,
+    return _contiguous(q, k, v, q_pos, k_pos, None, layer, causal=causal,
                        window=window, softcap=softcap, block_k=block_k,
                        interpret=interpret)
 
@@ -258,14 +302,15 @@ def flash_decode_pallas_quant(q, kq, vq, q_pos, k_pos, k_scale, v_scale,
                               *, causal: bool = True, window=None,
                               softcap: Optional[float] = None,
                               block_k: int = 512,
-                              interpret: bool = False):
+                              interpret: bool = False, layer=None):
     """Grouped split-KV flash decode over a quantized contiguous cache.
 
     Same contract as ``flash_decode_pallas`` except ``kq, vq (B, T, K,
     d)`` are int8/fp8 and ``k_scale, v_scale (B, T, K)`` carry the f32
-    per-(token, head) scales, blocked on the same map as their data.
+    per-(token, head) scales, blocked on the same map as their data
+    (stacked (L, B, T, K) with ``layer``).
     """
-    return _contiguous(q, kq, vq, q_pos, k_pos, (k_scale, v_scale),
+    return _contiguous(q, kq, vq, q_pos, k_pos, (k_scale, v_scale), layer,
                        causal=causal, window=window, softcap=softcap,
                        block_k=block_k, interpret=interpret)
 

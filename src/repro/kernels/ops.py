@@ -52,7 +52,8 @@ def _per_shard(kernel, q, k, v, q_pos, k_pos, window):
 
 
 def flash_attention(q, k, v, q_pos, k_pos, *, causal=True, window=None,
-                    softcap=None, chunk=1024, k_scale=None, v_scale=None):
+                    softcap=None, chunk=1024, k_scale=None, v_scale=None,
+                    layer=None):
     """Backend-dispatched flash attention.
 
     q: (B, S, H, d); k, v: (B, T, K, d) where K may be the NATIVE
@@ -67,10 +68,19 @@ def flash_attention(q, k, v, q_pos, k_pos, *, causal=True, window=None,
     quantized (int8/fp8) cache and decode dispatches to the
     dequantize-in-kernel variant; only S == 1 supports scales here
     (multi-token callers dequantize before calling).
+
+    With ``layer``, k, v (and the scales) are a decode loop's stacked
+    (L, B, T, ...) cache and k_pos (L, B, T): the decode kernel reads
+    that layer in place; every other path takes the layer's slice.
     """
     mode = _use_pallas()
     interpret = mode == "interpret"
     quant = k_scale is not None
+    if layer is not None and (mode is None or q.shape[1] != 1):
+        k, v, k_pos = k[layer], v[layer], k_pos[layer]
+        if quant:
+            k_scale, v_scale = k_scale[layer], v_scale[layer]
+        layer = None
     if q.shape[1] == 1:
         # decode: grouped split-KV kernel / pure-jnp twin (forward-only)
         if mode is not None:
@@ -80,10 +90,10 @@ def flash_attention(q, k, v, q_pos, k_pos, *, causal=True, window=None,
                 return flash_decode_pallas_quant(
                     q, k, v, q_pos, k_pos, k_scale, v_scale,
                     causal=causal, window=window, softcap=softcap,
-                    interpret=interpret)
+                    interpret=interpret, layer=layer)
             return flash_decode_pallas(
                 q, k, v, q_pos, k_pos, causal=causal, window=window,
-                softcap=softcap, interpret=interpret)
+                softcap=softcap, interpret=interpret, layer=layer)
         if quant:
             from repro.kernels.quant import flash_decode_quant_ref
             return flash_decode_quant_ref(

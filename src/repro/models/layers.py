@@ -217,6 +217,7 @@ def attention(
     causal: bool = True,
     window: Optional[Any] = None,       # None | int | traced scalar
     cache_kv: Optional[Tuple] = None,   # (k, v, k_positions) for decode/cross
+    cache_layer: Optional[Any] = None,  # layer of a stacked contiguous cache
     kv_x: Optional[jax.Array] = None,   # cross-attention source
     use_rope: bool = True,              # False for cross-attention
     chunked_threshold: int = 2048,
@@ -242,6 +243,11 @@ def attention(
                                       scales; S == 1 dequantizes inside
                                       the flash-decode kernels, S > 1
                                       dequantizes before attending
+      * in-place decode:              a contiguous cache_kv (plain or
+                                      quantized) stacked over layers,
+                                      (L, B, T, ...), with cache_layer
+                                      the layer to read — the decode
+                                      kernel reads it without a copy
     """
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = H // K
@@ -290,7 +296,7 @@ def attention(
                 k, v, k_pos, k_scale, v_scale = cache_kv
             else:
                 k, v, k_pos = cache_kv
-            T = k.shape[1]
+            T = k.shape[-3]
         if not use_rope:
             q_pos = positions if positions.ndim <= 2 else positions[0]
         elif cfg.m_rope_sections is not None:
@@ -345,8 +351,9 @@ def attention(
         # cache byte from HBM exactly once (groups× fewer bytes than
         # the retired repeat-then-attend path).
         from repro.kernels.ops import flash_attention
-        k = constrain(k, "batch", None, "cache_kv", None)
-        v = constrain(v, "batch", None, "cache_kv", None)
+        stack = (None,) * (k.ndim - 4)
+        k = constrain(k, *stack, "batch", None, "cache_kv", None)
+        v = constrain(v, *stack, "batch", None, "cache_kv", None)
         if quant and S > 1:
             # multi-token path dequantizes up front (decode S == 1 keeps
             # the narrow bytes all the way into the kernel)
@@ -356,7 +363,8 @@ def attention(
             k_scale = v_scale = None
         out = flash_attention(q, k, v, q_pos, k_pos_b, causal=causal,
                               window=window, softcap=cfg.logit_softcap,
-                              chunk=chunk, k_scale=k_scale, v_scale=v_scale)
+                              chunk=chunk, k_scale=k_scale, v_scale=v_scale,
+                              layer=cache_layer)
     else:
         # GQA -> per-shard MHA (see _expand_kv) keeps head sharding
         # aligned on the multi-token train/prefill paths.

@@ -127,9 +127,12 @@ def layer_windows(cfg: ModelConfig) -> Optional[jnp.ndarray]:
 # ---------------------------------------------------------------------------
 # Block applications (shared between train / prefill / decode)
 def _attn_mlp_block(p, x, cfg, *, positions, window, cache_kv=None,
-                    new_kv=None, moe_impl="sorted_capacity"):
+                    cache_layer=None, new_kv=None,
+                    moe_impl="sorted_capacity"):
     """Returns (x, aux dict, (k, v)) — k,v only when projecting fresh kv;
     aux carries {"aux_loss", "dropped_frac"} (zeros for non-MoE blocks).
+    ``cache_layer`` marks ``cache_kv`` as a stacked contiguous cache and
+    names the layer attention reads.
 
     Sequence parallelism (§Perf iteration A1): the residual stream and the
     norm regions live seq-sharded over the `model` axis; GSPMD then lowers
@@ -141,7 +144,8 @@ def _attn_mlp_block(p, x, cfg, *, positions, window, cache_kv=None,
     h = L.rms_norm(x, p["ln1"]["scale"], cfg.rms_eps)
     if cache_kv is not None:
         a = L.attention(p["attn"], h, cfg, positions=positions,
-                        cache_kv=cache_kv, window=window)
+                        cache_kv=cache_kv, cache_layer=cache_layer,
+                        window=window)
         kv = None
     else:
         a = L.attention(p["attn"], h, cfg, positions=positions, window=window)
@@ -166,7 +170,7 @@ def _ssm_block(p, x, cfg, cache=None, return_cache=False):
     return x + y, new_cache
 
 
-def _cache_write(kc, vc, pc, k_new, v_new, pos):
+def _cache_write(kc, vc, pc, k_new, v_new, pos, layer=None):
     """Write the new KV at each row's (ring) position.
 
     kc, vc: (B, T, K, hd); pc: (B, T); k_new, v_new: (B, 1, K, hd);
@@ -175,46 +179,49 @@ def _cache_write(kc, vc, pc, k_new, v_new, pos):
     scatter (the decode dry-run cells are memory-dominant) — or (B,)
     per-row positions for slot-pool serving, where rows at different
     sequence lengths land in different cache columns.  The pooled cache
-    stays shape-static either way."""
-    B, T = pc.shape
+    stays shape-static either way.
+
+    With ``layer`` (a traced scalar) kc, vc, pc are the stacked
+    (L, B, T, ...) caches of every layer and only that layer's B new
+    entries are written: the update is B x K x hd elements, in place,
+    whatever the stack's size."""
+    return _write_rows((kc, vc, pc), (k_new, v_new), pos, layer)
+
+
+def _write_rows(caches, news, pos, layer):
+    """Shared body of the contiguous cache writes: each of ``news`` (B,
+    1, ...) goes to its cache at (layer, row, slot), then the positions
+    (the last of ``caches``) get ``pos``.  Returns the caches."""
+    *data, pc = caches
+    B, T = pc.shape[-2:]
     slot = jnp.mod(pos.astype(jnp.int32), T)
+    lead = () if layer is None else (layer,)
     if pos.ndim == 0:
-        kc = jax.lax.dynamic_update_slice_in_dim(kc, k_new, slot, 1)
-        vc = jax.lax.dynamic_update_slice_in_dim(vc, v_new, slot, 1)
-        pc = jax.lax.dynamic_update_slice_in_dim(
-            pc, jnp.broadcast_to(pos, (B, 1)).astype(jnp.int32), slot, 1)
-        return kc, vc, pc
-    rows = jnp.arange(B)
-    kc = kc.at[rows, slot].set(k_new[:, 0])
-    vc = vc.at[rows, slot].set(v_new[:, 0])
-    pc = pc.at[rows, slot].set(pos.astype(jnp.int32))
-    return kc, vc, pc
+        col = jnp.broadcast_to(pos, (B, 1)).astype(jnp.int32)
+
+        def put(c, new):
+            new = new.reshape((1,) * len(lead) + new.shape)
+            start = lead + (0, slot) + (0,) * (new.ndim - len(lead) - 2)
+            return jax.lax.dynamic_update_slice(c, new, start)
+        return tuple(put(c, n) for c, n in zip(data, news)) + (put(pc, col),)
+    at = lead + (jnp.arange(B), slot)
+    return tuple(c.at[at].set(n[:, 0]) for c, n in zip(data, news)) + (
+        pc.at[at].set(pos.astype(jnp.int32)),)
 
 
-def _cache_write_quant(kc, vc, pc, ksc, vsc, k_new, v_new, pos, kv_dtype):
+def _cache_write_quant(kc, vc, pc, ksc, vsc, k_new, v_new, pos, kv_dtype,
+                       layer=None):
     """Quantize-at-scatter twin of ``_cache_write``.
 
     kc, vc: (B, T, K, hd) int8/fp8; ksc, vsc: (B, T, K) f32 scales —
     one per (token, head) vector, so an appended row quantizes
-    independently and no existing cache line is ever requantized."""
-    B, T = pc.shape
+    independently and no existing cache line is ever requantized.
+    ``layer`` as in ``_cache_write``."""
     kq, ks = Q.quantize_kv(k_new, kv_dtype)        # (B, 1, K, hd), (B, 1, K)
     vq, vs = Q.quantize_kv(v_new, kv_dtype)
-    kq, vq = kq.astype(kc.dtype), vq.astype(vc.dtype)
-    slot = jnp.mod(pos.astype(jnp.int32), T)
-    if pos.ndim == 0:
-        upd = jax.lax.dynamic_update_slice_in_dim
-        kc, vc = upd(kc, kq, slot, 1), upd(vc, vq, slot, 1)
-        ksc, vsc = upd(ksc, ks, slot, 1), upd(vsc, vs, slot, 1)
-        pc = upd(pc, jnp.broadcast_to(pos, (B, 1)).astype(jnp.int32),
-                 slot, 1)
-        return kc, vc, pc, ksc, vsc
-    rows = jnp.arange(B)
-    kc = kc.at[rows, slot].set(kq[:, 0])
-    vc = vc.at[rows, slot].set(vq[:, 0])
-    ksc = ksc.at[rows, slot].set(ks[:, 0])
-    vsc = vsc.at[rows, slot].set(vs[:, 0])
-    pc = pc.at[rows, slot].set(pos.astype(jnp.int32))
+    kc, vc, ksc, vsc, pc = _write_rows(
+        (kc, vc, ksc, vsc, pc),
+        (kq.astype(kc.dtype), vq.astype(vc.dtype), ks, vs), pos, layer)
     return kc, vc, pc, ksc, vsc
 
 
@@ -880,38 +887,9 @@ class DecoderModel:
                          cache["pos"]))
                 new_cache["k"], new_cache["v"], new_cache["pos"] = ks, vs, ps
             elif wl is None:
-                windows = layer_windows(cfg)
-                win_arr = (windows if windows is not None
-                           else jnp.full((cfg.num_layers,), BIG_WINDOW,
-                                         jnp.int32))
-                if "k_scale" in cache:
-                    def quant_body(h, xs):
-                        p_l, kc, vc, pc, ksc, vsc, win = xs
-                        hln = L.rms_norm(h, p_l["ln1"]["scale"], cfg.rms_eps)
-                        k_new, v_new = L.project_kv(p_l["attn"], hln, cfg,
-                                                    positions)
-                        kc, vc, pc, ksc, vsc = _cache_write_quant(
-                            kc, vc, pc, ksc, vsc, k_new, v_new, pos_row,
-                            self.kv_dtype)
-                        hn, _, _ = _attn_mlp_block(
-                            p_l, h, cfg, positions=positions, window=win,
-                            cache_kv=(kc, vc, pc, ksc, vsc),
-                            moe_impl=self.moe_impl)
-                        return hn, (kc, vc, pc, ksc, vsc)
-
-                    x, (ks, vs, ps, kss, vss) = jax.lax.scan(
-                        quant_body, x,
-                        (params["layers"], cache["k"], cache["v"],
-                         cache["pos"], cache["k_scale"], cache["v_scale"],
-                         win_arr))
-                    new_cache["k_scale"] = kss
-                    new_cache["v_scale"] = vss
-                else:
-                    x, (ks, vs, ps) = jax.lax.scan(
-                        make_body(), x,
-                        (params["layers"], cache["k"], cache["v"],
-                         cache["pos"], win_arr))
-                new_cache["k"], new_cache["v"], new_cache["pos"] = ks, vs, ps
+                x, stack = self._decode_in_place(params, x, positions,
+                                                 cache, pos_row)
+                new_cache.update(stack)
             elif not wl["global_idx"]:
                 # uniform sliding window (mixtral): ring caches everywhere
                 win_arr = jnp.full((cfg.num_layers,), cfg.sliding_window,
@@ -929,6 +907,46 @@ class DecoderModel:
         x = L.rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
         logits = L.unembed(params["embed"], x, cfg)[:, 0]
         return logits, new_cache
+
+    def _decode_in_place(self, params, x, positions, cache, pos_row):
+        """Decode over the stacked contiguous cache, updated in place.
+
+        The layer loop carries the stacked K, V and positions (and the
+        quantized cache's scales) instead of scanning them: each layer
+        writes its B new entries at (layer, row, slot) and attention
+        reads that layer of the carried stack, so nothing of the cache
+        is sliced out per layer or written back.  With the cache
+        donated, the carry is the caller's buffer.  Returns (x, the
+        updated cache leaves)."""
+        cfg = self.cfg
+        names = ("k", "v", "pos") + (
+            ("k_scale", "v_scale") if "k_scale" in cache else ())
+        windows = layer_windows(cfg)
+        win_arr = (windows if windows is not None
+                   else jnp.full((cfg.num_layers,), BIG_WINDOW, jnp.int32))
+
+        def body(carry, xs):
+            h, stack = carry
+            p_l, win, layer = xs
+            hln = L.rms_norm(h, p_l["ln1"]["scale"], cfg.rms_eps)
+            k_new, v_new = L.project_kv(p_l["attn"], hln, cfg, positions)
+            if len(stack) == 5:
+                stack = _cache_write_quant(*stack, k_new, v_new, pos_row,
+                                           self.kv_dtype, layer=layer)
+            else:
+                stack = _cache_write(*stack, k_new, v_new, pos_row,
+                                     layer=layer)
+            # cache_kv order: (k, v, k_pos[, k_scale, v_scale])
+            h, _, _ = _attn_mlp_block(
+                p_l, h, cfg, positions=positions, window=win,
+                cache_kv=stack, cache_layer=layer, moe_impl=self.moe_impl)
+            return (h, stack), None
+
+        (x, stack), _ = jax.lax.scan(
+            body, (x, tuple(cache[n] for n in names)),
+            (params["layers"], win_arr,
+             jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+        return x, dict(zip(names, stack))
 
     def _local_global_decode(self, params, x, positions, cache, new_cache,
                              wl, pos_row, B):

@@ -20,6 +20,13 @@ slots masked in-kernel.  Prefill compiles once per prompt-length
 bucket (``prefill_chunk`` rounds lengths up; pure-global-attention archs
 only — ring buffers and SSM state cannot mask pad tokens).
 
+The decode step takes the cache donated: the contiguous cache is
+updated in place (``DecoderModel.decode_step``), and
+``decode_cache_aliased_bytes`` against ``decode_cache_bytes`` shows
+that the compiled step aliases it.  Every reader of ``self.cache``
+between ticks (admission's ``scatter_prefill``, the ``len`` set before
+a tick) reads the step's latest output, never a donated buffer.
+
 Tracing: each tick's phases are host spans in the JAX profiler's trace
 (``repro.core.telemetry.span``), on the thread that calls ``step``:
 ``engine.admit`` per admission (args ``rid``, ``tokens``) holding
@@ -172,7 +179,11 @@ class Engine:
                                              mesh=self._mesh))
 
         self._prefill = jax.jit(model.prefill)
-        self._generate = jax.jit(make_generate_step(model))
+        # the cache is donated: the decode step updates it in place and
+        # hands it back, so a tick holds one cache, not two
+        self._generate = jax.jit(make_generate_step(model),
+                                 donate_argnums=(1,))
+        self._generate_compiles = 0
         self._sample1 = jax.jit(sample_tokens)
 
         # Paged KV: the cache becomes a GLOBAL pool of block_size-token
@@ -201,6 +212,11 @@ class Engine:
             self.block_size = self.num_blocks = None
             self.cache = model.init_cache(slots, cache_len)
             self.pool = SlotPool(slots)
+        # bytes of the cache, and those the compiled decode step aliases
+        # from its input to its output (None until it first compiles):
+        # at least the cache's own when it is updated in place
+        self.decode_cache_bytes = sum(a.nbytes for a in self.cache.values())
+        self.decode_cache_aliased_bytes: Optional[int] = None
         self.queue: List[InferenceRequest] = []
         self.requests: Dict[int, InferenceRequest] = {}
         self.finished: Dict[int, GenerationResult] = {}
@@ -473,8 +489,11 @@ class Engine:
                     self.last_tok, self.pool.positions(), self._seeds,
                     self._steps, self._temp, self._top_k, self._top_p)]
         with span("engine.decode"), self._scope():
-            tok, self.cache = self._generate(self.params, self.cache, *args,
+            donated = self.cache
+            tok, self.cache = self._generate(self.params, donated, *args,
                                              **extra)
+            if self._generate._cache_size() != self._generate_compiles:
+                self._record_aliasing(donated, args, extra)
         with span("engine.decode_sync"):
             tok_host = np.asarray(jax.block_until_ready(tok))
         with span("engine.emit"):
@@ -494,6 +513,17 @@ class Engine:
                 if last and self._slot_req[slot] is req:
                     self._retire(slot)
         return True
+
+    def _record_aliasing(self, cache, args, extra):
+        """Once per compile of the decode step: the bytes the compiled
+        program aliases from input to output.  The lowering is the one
+        the call just compiled (its donated, now deleted, cache gives
+        only shapes), so nothing compiles again."""
+        self._generate_compiles = self._generate._cache_size()
+        mem = self._generate.lower(self.params, cache, *args,
+                                   **extra).compile().memory_analysis()
+        if mem is not None:
+            self.decode_cache_aliased_bytes = int(mem.alias_size_in_bytes)
 
     def run(self, max_ticks: int = 1000) -> Dict[int, GenerationResult]:
         """Drive ticks until idle (or max_ticks). Returns finished results."""

@@ -19,7 +19,8 @@ try:
 except ImportError:                      # clean env: deterministic fallback
     from _hyp_fallback import given, settings, strategies as st
 
-from repro.kernels.flash_decode import flash_decode_pallas
+from repro.kernels.flash_decode import (contiguous_block, flash_decode_pallas,
+                                        flash_decode_pallas_quant)
 from repro.kernels.ref import (attention_oracle, flash_attention_ref,
                                flash_decode_ref)
 
@@ -162,15 +163,64 @@ def test_softcap(softcap):
     _check(q, k, v, qp, kp, softcap=softcap, block_k=32)
 
 
-@pytest.mark.parametrize("T,block_k", [(40, 16), (33, 32), (7, 512)])
+@pytest.mark.parametrize("T,block_k", [(40, 16), (33, 32), (7, 512),
+                                       (1280, 512), (1000, 512)])
 def test_cache_len_not_divisible_by_block_k(T, block_k):
     """Regression: T % block_k != 0 used to raise NotImplementedError;
-    the tail split is now padded with masked (-1 position) columns."""
+    the block now divides the row (1280 -> 256), or the last block is
+    ragged and masked in-kernel (40 / 16, 33 / 32, 1000 / 512), never
+    padded by a copy of the cache."""
     B, H, K, d = 2, 8, 2, 16
     q, k, v = _decode_inputs(B, T, H, K, d, seed=21)
     qp = jnp.full((B, 1), T, jnp.int32)
     kp = jnp.broadcast_to(jnp.arange(T), (B, T))
     _check(q, k, v, qp, kp, block_k=block_k)
+
+
+@pytest.mark.parametrize("T,block_k,want", [
+    (1280, 512, (256, False)), (5120, 512, (512, False)),
+    (1000, 512, (512, True)), (7, 512, (7, False)),
+    (64, 16, (16, False)), (40, 16, (16, True))])
+def test_contiguous_block_divides_or_masks(T, block_k, want):
+    """A lane-multiple divisor of the row up to block_k, else a ragged
+    last block; a row that fits is one block."""
+    assert contiguous_block(T, block_k) == want
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("T", [64, 40])
+def test_stacked_cache_each_layer_vs_ref(T, quant):
+    """The kernel reads layer l of a stacked (L, B, T, K, d) cache in
+    place, the layer a traced scalar, and equals the jnp twin on that
+    layer's slice for every l (layers filled to different lengths;
+    T = 40 leaves a ragged last block)."""
+    from repro.kernels import quant as Q
+    Lr, B, H, K, d = 3, 2, 8, 2, 16
+    ks = jax.random.split(jax.random.key(31), 3)
+    q = jax.random.normal(ks[0], (B, 1, H, d))
+    k = jax.random.normal(ks[1], (Lr, B, T, K, d))
+    v = jax.random.normal(ks[2], (Lr, B, T, K, d))
+    lengths = jnp.asarray([T - 3, 9])
+    t = jnp.arange(T)
+    kp = jnp.stack([jnp.where(t < (lengths - l)[:, None], t, -1)
+                    for l in range(Lr)])                     # (L, B, T)
+    qp = lengths
+    if quant:
+        (kq, kscale), (vq, vscale) = (Q.quantize_kv(a, "int8")
+                                      for a in (k, v))
+        run = jax.jit(lambda l: flash_decode_pallas_quant(
+            q, kq, vq, qp, kp, kscale, vscale, block_k=16,
+            interpret=True, layer=l))
+        twin = lambda l: Q.flash_decode_quant_ref(
+            q, kq[l], vq[l], qp, kp[l], kscale[l], vscale[l])
+    else:
+        run = jax.jit(lambda l: flash_decode_pallas(
+            q, k, v, qp, kp, block_k=16, interpret=True, layer=l))
+        twin = lambda l: flash_decode_ref(q, k[l], v[l], qp, kp[l])
+    for l in range(Lr):
+        np.testing.assert_allclose(np.asarray(run(jnp.int32(l))),
+                                   np.asarray(twin(l)), atol=2e-5,
+                                   rtol=2e-5)
 
 
 def test_fully_masked_row_returns_zeros():
@@ -276,3 +326,67 @@ def test_decode_step_matches_prefill_logits():
     full, _ = jax.jit(model.prefill)(params, {"tokens": toks})
     np.testing.assert_allclose(np.asarray(logits_d), np.asarray(full),
                                atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("mode", ["interpret", "jnp"])
+@pytest.mark.parametrize("T,lengths", [
+    (1280, (5, 0, 17)),           # ragged rows and an empty slot
+    (1000, (9, 3, 12)),           # no lane-multiple divisor: ragged block
+    (1280, (11, 11, 11)),         # one length: the scalar-pos write
+], ids=["ragged", "tail", "uniform"])
+def test_decode_in_place_matches_recompute(T, lengths, mode, monkeypatch):
+    """Three greedy decode steps over the stacked cache, updated in place,
+    give each row's next-token logits of a full forward over its
+    sequence (the tolerance of the test above) and the same greedy
+    tokens; through the Pallas kernel (interpret) and the jnp twin."""
+    from repro.configs import reduced_config
+    from repro.models.lm import DecoderModel
+
+    cfg = reduced_config("qwen3-32b")            # GQA: 4 heads over 2 kv
+    model = DecoderModel(cfg)
+    params = model.init(jax.random.key(0))
+    B, Sp = len(lengths), 32
+    uniform = len(set(lengths)) == 1
+    rng = np.random.default_rng(T + sum(lengths))
+    seqs = [list(rng.integers(2, cfg.vocab_size, n + 1)) for n in lengths]
+
+    def padded(rows):
+        toks = np.zeros((B, Sp), np.int32)
+        pos = np.full((B, Sp), -1, np.int32)
+        for b, r in enumerate(rows):
+            toks[b, :len(r)] = r
+            pos[b, :len(r)] = np.arange(len(r))
+        return {"tokens": jnp.asarray(toks), "positions": jnp.asarray(pos),
+                "length": jnp.asarray([max(len(r), 1) for r in rows],
+                                      jnp.int32)}
+
+    forward = jax.jit(model.prefill)
+    _, c = forward(params, padded([s[:-1] for s in seqs]))
+    cache = model.init_cache(B, T)
+    for name in ("k", "v", "pos"):
+        cache[name] = cache[name].at[:, :, :Sp].set(c[name])
+    cache["len"] = jnp.asarray(lengths[0], jnp.int32)
+    step = jax.jit(model.decode_step)
+    for _ in range(3):
+        cur = jnp.asarray([len(s) - 1 for s in seqs], jnp.int32)
+        batch = {"tokens": jnp.asarray([[s[-1]] for s in seqs], jnp.int32)}
+        if not uniform:
+            batch["positions"], batch["pos_row"] = cur[:, None], cur
+        with monkeypatch.context() as m:
+            if mode == "interpret":
+                m.setenv("REPRO_FORCE_PALLAS", "interpret")
+            else:
+                m.delenv("REPRO_FORCE_PALLAS", raising=False)
+            logits, cache = step(params, batch, cache)
+        want, _ = forward(params, padded(seqs))
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                                   atol=2e-2, rtol=2e-2)
+        got_tok = np.asarray(logits).argmax(-1)
+        np.testing.assert_array_equal(got_tok, np.asarray(want).argmax(-1))
+        for s, t in zip(seqs, got_tok):
+            s.append(int(t))
+    for b, s in enumerate(seqs):     # every layer holds the row's columns
+        n = len(s) - 1
+        np.testing.assert_array_equal(
+            np.asarray(cache["pos"][:, b, :n]),
+            np.broadcast_to(np.arange(n), (cfg.num_layers, n)))

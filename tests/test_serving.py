@@ -157,6 +157,42 @@ def test_mixed_lengths_all_families(arch):
     assert res[1].tokens == alone(pb)
 
 
+@pytest.mark.parametrize("arch", ["qwen3-32b", "gemma3-4b"])
+def test_engine_donated_cache_matches_undonated(arch):
+    """Ticks with the decode cache donated, requests admitted between
+    them (``scatter_prefill`` onto the last tick's output while its
+    donated input is gone): no deleted buffer is read, the tokens equal
+    those of an engine whose step does not donate, and the compiled
+    step aliases at least the whole cache (none without donation).
+    gemma3-4b keeps per-layer windows in ring caches."""
+    from repro.serving.engine import make_generate_step
+    cfg = reduced_config(arch)
+    model = build_model(cfg, remat="none")
+    params = model.init(jax.random.key(3))
+    rng = np.random.default_rng(5)
+    reqs = [(_prompt(rng, n, cfg.vocab_size), m)
+            for n, m in ((5, 3), (14, 7), (9, 4), (3, 6), (11, 5))]
+
+    def serve(donate):
+        e = Engine(model, params, slots=2, prefill_len=16, cache_len=48)
+        if not donate:
+            e._generate = jax.jit(make_generate_step(model))
+        rids = [e.submit(p, SamplingParams(max_new_tokens=m))
+                for p, m in reqs]
+        before = None
+        while e.queue or e.pool.num_active:
+            before = e.cache
+            e.step()
+        return e, [e.finished[r].tokens for r in rids], before
+
+    e, toks, before = serve(donate=True)
+    assert before["k" if "k" in before else "k_loc"].is_deleted()
+    plain, want, _ = serve(donate=False)
+    assert toks == want
+    assert e.decode_cache_aliased_bytes >= e.decode_cache_bytes > 0
+    assert plain.decode_cache_aliased_bytes == 0
+
+
 def test_slot_reuse_recycled_slot_does_not_leak(gemma):
     """A short request finishing frees its slot; the next queued request
     joins it and must see NONE of the previous occupant's history."""

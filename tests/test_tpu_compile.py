@@ -14,6 +14,9 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and under several
 test workers only the one given this file should.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -105,6 +108,63 @@ def test_flash_decode_paged(one_chip, kv_dtype):
     else:
         mem = _compile(one_chip, flash_decode_paged, q, pool, pool, *common)
     assert mem.temp_size_in_bytes < NB * BS * K * D_HEAD // 8
+
+
+# an op that moves a whole cache: `%x = bf16[dims]{layout} copy(...)`
+_CACHE_OP = re.compile(
+    r"= \w+\[([\d,]+)\]\S* (pad|dynamic-slice|dynamic-update-slice|copy)\(")
+
+
+def _moves_kv(line, B, T_row):
+    """Whether an HLO line pads, slices, writes back or copies one
+    layer's K/V (B, >= T_row, K, d) or the stack of them."""
+    m = _CACHE_OP.search(line)
+    if m is None:
+        return False
+    dims = [int(x) for x in m.group(1).split(",")]
+    while len(dims) > 4 and dims[0] == 1:
+        dims.pop(0)
+    return (len(dims) in (4, 5) and tuple(dims[-2:]) == (K, D_HEAD)
+            and dims[-4] == B and dims[-3] >= T_row)
+
+
+@pytest.mark.parametrize("B,T_row", [(64, 1280), (19, 5120)],
+                         ids=["batch", "chat"])
+def test_generate_step_updates_cache_in_place(one_chip, monkeypatch, B,
+                                              T_row):
+    """The serve decode tick at qwen3-32b widths (2 layers, a small
+    vocabulary) with the cache donated, as ``Engine`` compiles it: no
+    layer's K/V is sliced out, padded, written back or copied, the
+    program aliases the whole cache from input to output, and its
+    temporaries stay under one layer's K."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models.model import build_model
+    from repro.serving.engine import make_generate_step
+    monkeypatch.setattr(ops, "_use_pallas", lambda: "tpu")
+    cfg = dataclasses.replace(get_config("qwen3-32b"), num_layers=2,
+                              vocab_size=1024)
+    assert (cfg.num_kv_heads, cfg.head_dim) == (K, D_HEAD)
+    model = build_model(cfg, remat="none")
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip)
+    params = jax.tree.map(place, model.abstract_params(jnp.bfloat16))
+    cache = jax.tree.map(place, model.cache_spec(B, T_row))
+    rows = [place(jax.ShapeDtypeStruct((B,), dt)) for dt in (
+        jnp.int32, jnp.int32, jnp.uint32, jnp.int32, jnp.float32,
+        jnp.int32, jnp.float32)]
+    compiled = jax.jit(make_generate_step(model), donate_argnums=(1,)).lower(
+        params, cache, *rows).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    moved = [line.strip()[:160] for line in hlo.splitlines()
+             if _moves_kv(line, B, T_row)]
+    assert not moved, moved[:4]
+    mem = compiled.memory_analysis()
+    kvp = sum(cache[n].size * cache[n].dtype.itemsize
+              for n in ("k", "v", "pos"))
+    assert mem.alias_size_in_bytes >= kvp
+    assert mem.temp_size_in_bytes < B * T_row * K * D_HEAD * 2
 
 
 def test_moe_gemm_mixtral_widths(one_chip):
