@@ -29,9 +29,15 @@ The training loss is the program's recipe: mean token cross-entropy
 plus 1e-4 x mean log-sum-exp squared (z-loss), and AdamW with global
 norm clipping, bias correction and decoupled weight decay at a constant
 learning rate.
+
+A sharded train cell's reference runs over the cell's chips by a
+placement of its own, not the program's: the weights where ``spread``
+puts them, the activations split by heads, FFN columns and vocabulary
+(``_split``).  On one device both are no-ops.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Dict, Sequence
@@ -101,6 +107,25 @@ def make_params(abstract, seed32: int, dtype, out_shardings=None):
     return fn(jax.random.key(seed32))
 
 
+def spread(abstract, devices):
+    """The reference's own placement over several devices, apart from
+    the program's rules: each leaf split along its largest axis that the
+    device count divides (replicated where none does), on a 1-D mesh.
+    The jitted steps below then run over all of them."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    mesh = Mesh(np.asarray(devices), ("d",))
+    n = len(devices)
+
+    def one(a):
+        spec = [None] * len(a.shape)
+        fits = [i for i, d in enumerate(a.shape) if d % n == 0]
+        if fits:
+            spec[max(fits, key=lambda i: a.shape[i])] = "d"
+        return NamedSharding(mesh, PartitionSpec(*spec))
+
+    return jax.tree.map(one, abstract)
+
+
 # ---------------------------------------------------------------------------
 # numerics
 def _round8(t, dtype, top):
@@ -133,6 +158,19 @@ def _mm(eq, a, b, low):
     if low:
         return _g8(jnp.einsum(eq, _q8(a), _q8(b), precision=HIGHEST))
     return jnp.einsum(eq, a, b, precision=HIGHEST)
+
+
+def _split(x, axis: int):
+    """Lay an activation out split along ``axis`` over the reference's
+    devices, where it runs on several (``train_steps`` sets their mesh);
+    on one device, x as it is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return x
+    spec = [None] * x.ndim
+    spec[axis] = mesh.axis_names[0]
+    return jax.lax.with_sharding_constraint(x, jax.sharding.PartitionSpec(
+        *spec))
 
 
 def _rms(x, scale, eps):
@@ -170,20 +208,21 @@ def _layer(p, x, *, spec, q_block: int, low: bool, train: bool):
     q = _rms(_mm("bsd,dhk->bshk", h, a["wq"], low), a["q_norm"], eps)
     k = _rms(_mm("bsd,dhk->bshk", h, a["wk"], low), a["k_norm"], eps)
     v = _mm("bsd,dhk->bshk", h, a["wv"], low)
-    q = _rope(q, pos, spec["rope_theta"])
+    q = _split(_rope(q, pos, spec["rope_theta"]), 2)
     k = _rope(k, pos, spec["rope_theta"])
     groups = spec["num_attention_heads"] // spec["num_key_value_heads"]
-    k = jnp.repeat(k, groups, axis=2)                  # head h -> h // G
-    v = jnp.repeat(v, groups, axis=2)
+    k = _split(jnp.repeat(k, groups, axis=2), 2)       # head h -> h // G
+    v = _split(jnp.repeat(v, groups, axis=2), 2)
     attend = jax.checkpoint(_attend_block, static_argnums=(3, 4, 5)) \
         if train else _attend_block
-    o = jnp.concatenate([attend(q[:, lo:lo + q_block], k, v, lo, hd, low)
-                         for lo in range(0, S, q_block)], 1)
+    o = _split(jnp.concatenate([attend(q[:, lo:lo + q_block], k, v, lo, hd,
+                                       low)
+                                for lo in range(0, S, q_block)], 1), 2)
     x = x + _mm("bshk,hkd->bsd", o, a["wo"], low)
     h = _rms(x, p["ln2"]["scale"], eps)
     m = p["mlp"]
-    g = jax.nn.silu(_mm("bsd,df->bsf", h, m["w1"], low)) \
-        * _mm("bsd,df->bsf", h, m["w3"], low)
+    g = _split(jax.nn.silu(_mm("bsd,df->bsf", h, m["w1"], low))
+               * _mm("bsd,df->bsf", h, m["w3"], low), 2)
     return x + _mm("bsf,fd->bsd", g, m["w2"], low)
 
 
@@ -255,11 +294,14 @@ def loss(params, tokens, labels, spec, low=False, q_block=512):
     x = params["embed"]["embedding"].astype(F32)[tokens]
     layer = jax.checkpoint(functools.partial(
         _layer, spec=spec, q_block=q_block, low=low, train=True))
-    for i in range(spec["num_hidden_layers"]):
-        x = layer(jax.tree.map(lambda t: t[i], params["layers"]), x)
+    # a scan over the stacked layers writes each layer's gradient into
+    # the stacked gradient in place (a loop of slices would add a copy)
+    x, _ = jax.lax.scan(lambda x, p: (layer(p, x), None), x,
+                        params["layers"])
     y = _rms(x, params["final_norm"]["scale"].astype(F32),
              spec["rms_norm_eps"])
-    lg = _mm("bsd,dv->bsv", y, params["embed"]["unembed"].astype(F32), low)
+    lg = _split(_mm("bsd,dv->bsv", y, params["embed"]["unembed"].astype(F32),
+                    low), 2)
     lse = jax.nn.logsumexp(lg, -1)
     gold = jnp.take_along_axis(lg, jnp.maximum(labels, 0)[..., None],
                                -1)[..., 0]
@@ -304,6 +346,13 @@ def leaf_norms(tree):
         a.astype(F32)))), tree)
 
 
+@jax.jit
+def gap_norms(a, b):
+    """Per-leaf norm of a - b, in one program that holds no difference."""
+    return jax.tree.map(lambda x, y: jnp.sqrt(jnp.sum(jnp.square(
+        x.astype(F32) - y.astype(F32)))), a, b)
+
+
 def train_steps(params0, spec: Dict, batches, opt, *, low: bool = False):
     """Run len(batches) AdamW steps from ``params0`` (float32, consumed).
 
@@ -314,7 +363,10 @@ def train_steps(params0, spec: Dict, batches, opt, *, low: bool = False):
     m = jax.tree.map(jnp.zeros_like, p)
     v = jax.tree.map(jnp.zeros_like, p)
     losses, gnorm1 = [], None
-    with jax.default_matmul_precision("highest"):
+    mesh = getattr(jax.tree.leaves(p)[0].sharding, "mesh", None)
+    with jax.default_matmul_precision("highest"), (
+            jax.set_mesh(mesh) if mesh is not None
+            else contextlib.nullcontext()):
         for i, b in enumerate(batches):
             val, g = _value_and_grad(p, jnp.asarray(b["tokens"]),
                                      jnp.asarray(b["labels"]), spec, low)

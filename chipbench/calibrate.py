@@ -2,9 +2,10 @@
 """Readings that set a cell's limits and rate; not part of a benchmark run.
 
     # the numbers compared, for the program and for the fp8 control, on
-    # many seeds in one process (one JSON line per seed):
+    # many seeds in one process (one JSON line per seed); --fault plants
+    # one of chipbench/faults.py's faults in the program:
     python3 chipbench/calibrate.py check --workload <name> --seeds 1,2,3 \\
-        --seconds 30 [--control]
+        --seconds 30 [--control] [--fault keep|exchange]
 
     # an open-loop cell's latency at several rates (one set-up), to find
     # the highest rate the system sustains:
@@ -16,6 +17,7 @@ Both run on the chips of this machine and exit non-zero without them.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import gc
 import json
 import sys
@@ -25,14 +27,17 @@ import run as entry                        # chipbench/run.py
 
 
 def check(args, devs) -> None:
-    from chipbench import cells
+    from chipbench import cells, faults
     from chipbench.bench import find_cell
     cell = find_cell(args.workload)
     for seed in (int(s) for s in args.seeds.split(",")):
         t = time.perf_counter()
-        m = cells.measure(cell, devs, seed, args.seconds)
+        with (faults.FAULTS[args.fault]() if args.fault
+              else contextlib.nullcontext()):
+            m = cells.measure(cell, devs, seed, args.seconds)
         values, ctrl = cells.compared(m, control=args.control)
-        print(json.dumps({"seed": seed, "program": values, "control": ctrl,
+        print(json.dumps({"seed": seed, "fault": args.fault,
+                          "program": values, "control": ctrl,
                           "e2e": m.e2e, "attempted": m.attempted,
                           "failed": m.failed, "compiles": m.compiles,
                           "memory_peak_bytes": m.memory_peak_bytes,
@@ -68,6 +73,7 @@ def main(argv=None) -> int:
     c.add_argument("--seeds", required=True)
     c.add_argument("--seconds", type=float, required=True)
     c.add_argument("--control", action="store_true")
+    c.add_argument("--fault", choices=("keep", "exchange"))
     s = sub.add_parser("sweep")
     s.add_argument("--workload", required=True)
     s.add_argument("--seed", type=int, required=True)

@@ -18,9 +18,17 @@ per-leaf norms of the change after three steps, a leaf's gap taken
 against the larger of its reference norm and the median leaf's.  Leaves
 whose reference gradient is under ``NOUGHT`` x the median leaf's move by
 round-off alone under Adam and are left out of both norms' comparison.
+
+A configuration whose ``train`` section names a ``plan`` (``model=4``)
+runs sharded over the cell's chips: the trainer takes the resolved plan,
+and the harness draws the initial weights and AdamW state straight into
+the shardings the plan gives the trainer's state, since the unsharded
+float32 state does not fit one chip.  Its reference spreads each leaf
+over the same chips by a placement of its own (``arch.spread``).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import gc
 import time
@@ -122,6 +130,7 @@ def opt_tuple(tr: Dict) -> tuple:
 def setup(cell: Cell, seed: int):
     """The trainer, its first CHECK_STEPS steps, and what the check reads
     of them: (trainer, steps callback, readings dict)."""
+    import jax
     import jax.numpy as jnp
     from repro.core.config import (ParallelConfig, RunConfig, ShapeConfig,
                                    StepKind)
@@ -130,8 +139,6 @@ def setup(cell: Cell, seed: int):
     from repro.train.step import TrainState
 
     tr = cell.config["train"]
-    if tr.get("plan"):
-        raise NotImplementedError("sharded train cells are not built yet")
     cfg = program_config(cell.config)
     arch = arch_module(cell.config)
     s32 = traffic.seed32(seed)
@@ -141,13 +148,23 @@ def setup(cell: Cell, seed: int):
                         parallel=ParallelConfig(remat=tr["remat"]),
                         optimizer=_opt_config(tr), param_dtype="float32",
                         seed=s32)
+    plan = None
+    if tr.get("plan"):
+        from repro.parallel.plan import resolve_plan
+        plan = resolve_plan(tr["plan"], cfg, chips=cell.chips, shape=shape)
     steps = Steps(arch)
-    trainer = runtime.Trainer(run_cfg, callbacks=[steps])
+    trainer = runtime.Trainer(run_cfg, plan=plan, callbacks=[steps])
 
     def init_state(model, run_cfg, key):
-        params = arch.make_params(model.abstract_params(jnp.float32), s32,
-                                  jnp.float32)
-        return TrainState(params=params, opt=adamw_init(params), ef=None)
+        abstract = model.abstract_params(jnp.float32)
+        if plan is None:
+            params = arch.make_params(abstract, s32, jnp.float32)
+            return TrainState(params=params, opt=adamw_init(params), ef=None)
+        sh = state_shardings(plan, model, run_cfg)
+        params = arch.make_params(abstract, s32, jnp.float32,
+                                  out_shardings=sh.params)
+        opt = jax.jit(adamw_init, out_shardings=sh.opt)(params)
+        return TrainState(params=params, opt=opt, ef=None)
 
     with mock.patch.object(runtime, "init_train_state", init_state):
         trainer.setup()
@@ -162,20 +179,35 @@ def setup(cell: Cell, seed: int):
     trainer.step_fn = recording
     report = trainer.run(CHECK_STEPS)
     trainer.step_fn = step_fn
+    params = trainer.train_state.params
     readings = {"losses": list(report.losses), "first_grad": steps.first_grad,
-                "change": change_norms(arch, trainer.train_state.params,
-                                       trainer.model, s32),
+                "change": change_norms(
+                    arch, params, trainer.model, s32, None if plan is None
+                    else jax.tree.map(lambda a: a.sharding, params)),
                 "batches": fed}
     return trainer, steps, readings
 
 
-def change_norms(arch, params, model, s32: int) -> Dict[str, float]:
-    """Per-leaf norm of params minus the seed's initial weights."""
+def state_shardings(plan, model, run_cfg):
+    """The shardings the trainer gives its state under ``plan``, on the
+    mesh it builds: the plan's mesh over the first ``plan.chips``
+    devices."""
     import jax
+    from repro.train.step import (abstract_train_state,
+                                  train_state_logical_axes)
+    mesh = plan.mesh(devices=jax.devices()[:plan.chips])
+    return plan.shardings(abstract_train_state(model, run_cfg),
+                          train_state_logical_axes(model, run_cfg), mesh=mesh)
+
+
+def change_norms(arch, params, model, s32: int,
+                 shardings=None) -> Dict[str, float]:
+    """Per-leaf norm of params minus the seed's initial weights, drawn
+    with ``shardings`` (None: on the default device)."""
     import jax.numpy as jnp
     p0 = arch.make_params(model.abstract_params(jnp.float32), s32,
-                          jnp.float32)
-    out = _flat(arch.leaf_norms(jax.tree.map(jnp.subtract, params, p0)))
+                          jnp.float32, out_shardings=shardings)
+    out = _flat(arch.gap_norms(params, p0))
     del p0
     return out
 
@@ -197,7 +229,11 @@ def window(cell: Cell, trainer, steps: Steps, seconds: float,
     t0 = time.perf_counter()
     steps.t_end = t0 + seconds
     steps.open_span()
-    report = trainer.run()
+    # the step was traced under the plan's mesh and rules; the first run
+    # left them at its end, and the window calls it as it was compiled
+    with (trainer.plan.activate(trainer.mesh) if trainer.plan is not None
+          else contextlib.nullcontext()):
+        report = trainer.run()
     steps.close_span()
     win.__exit__(None, None, None)
     in_window = counter.events - c0
@@ -217,16 +253,20 @@ def free(trainer) -> None:
 
 def reference(arch, cell: Cell, model, seed: int, batches, *,
               low: bool = False) -> Dict:
-    """The reference's readings of the same CHECK_STEPS steps."""
+    """The reference's readings of the same CHECK_STEPS steps, over the
+    cell's chips."""
+    import jax
     import jax.numpy as jnp
     s32 = traffic.seed32(seed)
-    p0 = arch.make_params(model.abstract_params(jnp.float32), s32,
-                          jnp.float32)
+    abstract = model.abstract_params(jnp.float32)
+    shardings = (arch.spread(abstract, jax.devices()[:cell.chips])
+                 if cell.chips > 1 else None)
+    p0 = arch.make_params(abstract, s32, jnp.float32, out_shardings=shardings)
     losses, g1, p3 = arch.train_steps(p0, cell.config, batches[:CHECK_STEPS],
                                       opt_tuple(cell.config["train"]),
                                       low=low)
     return {"losses": losses, "first_grad": _flat(g1),
-            "change": change_norms(arch, p3, model, s32)}
+            "change": change_norms(arch, p3, model, s32, shardings)}
 
 
 def gaps(prog: Dict, ref: Dict) -> Dict[str, float]:

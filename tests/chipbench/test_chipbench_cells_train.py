@@ -7,7 +7,7 @@ import json
 
 import jax
 
-from chipbench import cells
+from chipbench import cells, faults
 from chipbench.bench import check
 from chipbench_tiny import tiny_cell
 
@@ -23,22 +23,11 @@ def test_train_is_correct_and_its_control_is_not():
     assert not all(check(cell, k, v).ok for k, v in control.items()), control
 
 
-def test_a_step_that_keeps_its_state_is_caught(monkeypatch):
-    import repro.train.runtime as runtime
-    real = runtime.make_train_step
-
-    def frozen(model, run_cfg):
-        step = real(model, run_cfg)
-
-        def keep(state, batch):
-            _, metrics = step(state, batch)
-            return state, metrics
-        return keep
-
-    monkeypatch.setattr(runtime, "make_train_step", frozen)
+def test_a_step_that_keeps_its_state_is_caught():
     lines = []
-    cells.run(tiny_cell("qwen3-32b-train.pack4k"), jax.devices()[:1],
-              seed=SEED, seconds=1.0, out=lines.append)
+    with faults.kept_state():
+        cells.run(tiny_cell("qwen3-32b-train.pack4k"), jax.devices()[:1],
+                  seed=SEED, seconds=1.0, out=lines.append)
     out = json.loads(lines[-1])
     assert out["correct"] is False
     assert out["checks"]["change_norm_gap"]["value"] > 0.99

@@ -127,3 +127,24 @@ def test_limits_files_hold_the_readings_they_came_from(c):
     for name, lim in limits.items():
         assert lim["lower"] < lim["limit"] < lim["upper"], name
         assert lim["from"]
+
+
+@pytest.mark.parametrize("m", M["per_layer"], ids=lambda m: m["name"])
+def test_per_layer_metrics_list_their_cells(m):
+    # a reader that finds nothing in a cell returns None, and a cell
+    # whose --trace 1 line lacks a metric it should report is refused
+    assert m["workloads"]
+
+
+@pytest.mark.parametrize("c", CELLS, ids=lambda c: c["name"])
+def test_a_cell_on_four_chips_runs_a_plan_over_them(c):
+    from chipbench.bench import program_config
+    from repro.parallel.plan import resolve_plan
+    g = next(g for g in M["configs"] if g["name"] == c["config"])
+    cfg = load_json(ROOT / g["file"])
+    plan = cfg.get(cfg["kind"], {}).get("plan")
+    if c["chips"] == 1:
+        assert plan is None
+    else:
+        assert resolve_plan(plan, program_config(cfg),
+                            chips=c["chips"]).chips == c["chips"]
